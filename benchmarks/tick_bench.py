@@ -184,8 +184,6 @@ def roofline_row(cfg, rows, tick, fused_ms):
         lambda s, b, sc, c, v: _fused_tick(s, b, sc, c, v, cfg, False)
     ).lower(state, *tick).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):      # older jax: one dict per device
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     compute_ms = flops / PEAK_FLOPS * 1e3

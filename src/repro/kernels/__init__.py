@@ -17,9 +17,15 @@ one launch per micro-batch).  Layout and tiling choices:
 * Within a tile, greedy NMS is solved by a suppression *fixpoint*
   (3-5 vectorized sweeps) instead of a serial per-box loop; the tile
   loop exits early once ``max_out`` survivors exist.
-* Survivor -> output-slot assignment is an O(A) exclusive cumsum over
-  the alive mask — never a dense (A, A) triangular product, which
-  would put the quadratic operand back into VMEM.
+* Survivor -> output-slot assignment counts survivors tile by tile
+  (a (tile, A) masked sum) — never a dense (A, A) triangular product,
+  which would put the quadratic operand back into VMEM.
+
+Every ``*_pallas`` entry takes ``interpret`` with no default:
+``ops`` passes ``ops._interpret()``, the one place that picks Mosaic
+(TPU) or the interpreter (any other backend), and a direct caller must
+say which.  ``tests/test_tpu_compile.py`` compiles the frame-path
+kernels for a TPU v5e on every test run.
 
 ``nms.batched_nms_xla`` is the same algorithm as batched XLA ops and is
 the production path on hosts where Pallas runs interpreted;

@@ -8,9 +8,10 @@ the threshold.  Done naively this is a host-side Hungarian/greedy loop
 per frame; here it is one launch per frame batch:
 
  1. **Cost matrix**: the (T, D) IoU matrix of predicted track boxes vs
-    detection boxes is computed on the fly in VMEM from (4, T) / (4, D)
-    coordinate planes (same transposed layout as ``iou.py`` /
-    ``nms.py`` — the pair index lands on the 128-wide lane dimension).
+    detection boxes is computed on the fly in VMEM from (T, 4) track
+    rows and (4, D) detection planes: tracks run down the sublanes,
+    detections along the 128-wide lanes, with no transpose in the
+    kernel.
     Pairs that are masked out (dead track slot, padding detection) or
     class-mismatched are set to cost -1 so they can never win.
  2. **Greedy assignment**: at most ``min(T, D)`` serial steps; each
@@ -26,12 +27,12 @@ embarrassingly vectorizable; the oracle in ``ref.greedy_assign_ref``
 pins the exact semantics and both paths are bit-compatible with it.
 
 On TPU the ``pallas_call`` compiles to Mosaic (grid = batch, one
-program per frame); on the CPU host it runs in interpret mode.
+program per frame; ``chip_smoke.py`` checks it against the XLA twin on
+the chip); on the CPU host it runs in interpret mode.
 ``greedy_assign_xla`` is the same algorithm as batched XLA ops with a
 per-frame active gate and is the production path on non-TPU hosts —
 see ``ops.greedy_assign`` for the dispatch.  TPU tile tuning (lane-
-width padding of T/D, VMEM residency) is a ROADMAP follow-up; only
-interpret mode is validated so far.
+width padding of T/D, VMEM residency) is a ROADMAP follow-up.
 """
 from __future__ import annotations
 
@@ -45,28 +46,35 @@ from .nms import _pair_iou
 
 
 def _plane_cost(tb, db, t_ok, d_ok, t_cls, d_cls):
-    """IoU of (4, T) track planes vs (4, D) detection planes, masked to
-    -1 where either side is dead/padding or the classes differ."""
-    tx0, ty0, tx1, ty1 = tb[0], tb[1], tb[2], tb[3]
-    dx0, dy0, dx1, dy1 = db[0], db[1], db[2], db[3]
-    ix0 = jnp.maximum(tx0[:, None], dx0[None, :])
-    iy0 = jnp.maximum(ty0[:, None], dy0[None, :])
-    ix1 = jnp.minimum(tx1[:, None], dx1[None, :])
-    iy1 = jnp.minimum(ty1[:, None], dy1[None, :])
+    """IoU of (T, 4) track rows vs (4, D) detection planes -> (T, D),
+    masked to -1 where either side is dead/padding or the classes
+    differ.  ``t_ok``/``t_cls`` are (T, 1) columns, ``d_ok``/``d_cls``
+    (1, D) lanes."""
+    tx0, ty0, tx1, ty1 = (tb[:, k:k + 1] for k in range(4))
+    dx0, dy0, dx1, dy1 = (db[k:k + 1] for k in range(4))
+    ix0 = jnp.maximum(tx0, dx0)
+    iy0 = jnp.maximum(ty0, dy0)
+    ix1 = jnp.minimum(tx1, dx1)
+    iy1 = jnp.minimum(ty1, dy1)
     inter = jnp.clip(ix1 - ix0, 0.0) * jnp.clip(iy1 - iy0, 0.0)
     t_area = (tx1 - tx0) * (ty1 - ty0)
     d_area = (dx1 - dx0) * (dy1 - dy0)
-    union = t_area[:, None] + d_area[None, :] - inter
+    union = t_area + d_area - inter
     iou = inter / jnp.maximum(union, 1e-9)
-    ok = ((t_ok[:, None] > 0) & (d_ok[None, :] > 0) &
-          (t_cls[:, None] == d_cls[None, :]))
+    ok = (t_ok > 0) & (d_ok > 0) & (t_cls == d_cls)
     return jnp.where(ok, iou, -1.0)
 
 
-def _greedy_body(n_pairs, iou_thr, Dp, cost0, match0):
-    """Shared greedy loop (runs inside the Pallas kernel): commit the
-    best remaining pair per step, retire its row+column."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (cost0.shape[0], 1), 0)[:, 0]
+def _greedy_body(n_pairs, iou_thr, cost0):
+    """Greedy loop (runs inside the Pallas kernel): commit the best
+    remaining pair per step (lowest row-major index among ties, like
+    ``argmax``), retire its row+column.  Returns the (T, 1) match
+    column."""
+    Tp, Dp = cost0.shape
+    rowm = jax.lax.broadcasted_iota(jnp.int32, (Tp, Dp), 0)
+    colm = jax.lax.broadcasted_iota(jnp.int32, (Tp, Dp), 1)
+    flat_idx = rowm * Dp + colm
+    row = jax.lax.broadcasted_iota(jnp.int32, (Tp, 1), 0)
 
     def cond(state):
         it, cost, _ = state
@@ -74,17 +82,17 @@ def _greedy_body(n_pairs, iou_thr, Dp, cost0, match0):
 
     def body(state):
         it, cost, match = state
-        flat = jnp.argmax(cost).astype(jnp.int32)
-        i = flat // Dp
+        best = jnp.max(cost)
+        flat = jnp.min(jnp.where(cost == best, flat_idx, Tp * Dp))
+        i = jax.lax.div(flat, Dp)
         j = flat - i * Dp
         match = jnp.where(row == i, j, match)
-        col = jax.lax.broadcasted_iota(jnp.int32, cost.shape, 1)
-        rowm = jax.lax.broadcasted_iota(jnp.int32, cost.shape, 0)
-        cost = jnp.where((rowm == i) | (col == j), -1.0, cost)
+        cost = jnp.where((rowm == i) | (colm == j), -1.0, cost)
         return it + 1, cost, match
 
-    _, _, match = jax.lax.while_loop(cond, body,
-                                     (jnp.int32(0), cost0, match0))
+    _, _, match = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), cost0,
+                     jnp.full((Tp, 1), -1, jnp.int32)))
     return match
 
 
@@ -94,29 +102,31 @@ def _assoc_kernel(tb_ref, tm_ref, tc_ref, db_ref, dm_ref, dc_ref,
     cost = _plane_cost(tb_ref[0].astype(jnp.float32),
                        db_ref[0].astype(jnp.float32),
                        tm_ref[0], dm_ref[0], tc_ref[0], dc_ref[0])
-    match_ref[0, :] = _greedy_body(
-        n_pairs, iou_thr, cost.shape[1], cost,
-        jnp.full((cost.shape[0],), -1, jnp.int32))
+    match_ref[0] = _greedy_body(n_pairs, iou_thr, cost)
 
 
 @functools.partial(jax.jit, static_argnames=("iou_thr", "interpret"))
 def greedy_assign_pallas(t_boxes, d_boxes, t_mask, d_mask, t_cls, d_cls,
-                         *, iou_thr=0.3, interpret=True):
+                         *, interpret, iou_thr=0.3):
     """t_boxes (B, T, 4) xyxy, d_boxes (B, D, 4) xyxy (+ per-slot masks
     and int class ids) -> match (B, T) int32: the detection index
-    assigned to each track slot, or -1.  One launch per frame batch."""
+    assigned to each track slot, or -1.  One launch per frame batch.
+    ``interpret`` has no default: the caller (``ops.greedy_assign``)
+    decides from the platform."""
     B, T, _ = t_boxes.shape
     D = d_boxes.shape[1]
     t_pad = -T % 8
     d_pad = -D % 8
     tb = jnp.pad(t_boxes.astype(jnp.float32), ((0, 0), (0, t_pad), (0, 0)))
     db = jnp.pad(d_boxes.astype(jnp.float32), ((0, 0), (0, d_pad), (0, 0)))
-    tm = jnp.pad(t_mask.astype(jnp.int32), ((0, 0), (0, t_pad)))
-    dm = jnp.pad(d_mask.astype(jnp.int32), ((0, 0), (0, d_pad)))
-    tc = jnp.pad(t_cls.astype(jnp.int32), ((0, 0), (0, t_pad)))
-    dc = jnp.pad(d_cls.astype(jnp.int32), ((0, 0), (0, d_pad)))
+    # tracks go down the sublanes as (Tp, 1) columns, detections along
+    # the lanes as (1, Dp) rows, so the (Tp, Dp) cost plane needs no
+    # transpose inside the kernel
+    tm = jnp.pad(t_mask.astype(jnp.int32), ((0, 0), (0, t_pad)))[..., None]
+    tc = jnp.pad(t_cls.astype(jnp.int32), ((0, 0), (0, t_pad)))[..., None]
+    dm = jnp.pad(d_mask.astype(jnp.int32), ((0, 0), (0, d_pad)))[:, None]
+    dc = jnp.pad(d_cls.astype(jnp.int32), ((0, 0), (0, d_pad)))[:, None]
     Tp, Dp = T + t_pad, D + d_pad
-    tbt = tb.transpose(0, 2, 1)                  # (B, 4, Tp) planes
     dbt = db.transpose(0, 2, 1)                  # (B, 4, Dp) planes
 
     kernel = functools.partial(_assoc_kernel, n_pairs=min(T, D),
@@ -125,18 +135,18 @@ def greedy_assign_pallas(t_boxes, d_boxes, t_mask, d_mask, t_cls, d_cls,
         kernel,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 4, Tp), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, Tp), lambda b: (b, 0)),
-            pl.BlockSpec((1, Tp), lambda b: (b, 0)),
+            pl.BlockSpec((1, Tp, 4), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, Tp, 1), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, Tp, 1), lambda b: (b, 0, 0)),
             pl.BlockSpec((1, 4, Dp), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, Dp), lambda b: (b, 0)),
-            pl.BlockSpec((1, Dp), lambda b: (b, 0)),
+            pl.BlockSpec((1, 1, Dp), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, 1, Dp), lambda b: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Tp), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Tp), jnp.int32),
+        out_specs=pl.BlockSpec((1, Tp, 1), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Tp, 1), jnp.int32),
         interpret=interpret,
-    )(tbt, tm, tc, dbt, dm, dc)
-    return match[:, :T]
+    )(tb, tm, tc, dbt, dm, dc)
+    return match[:, :T, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("iou_thr",))

@@ -7,7 +7,8 @@ GQA "repeat" never materializes), and the (S, D) cache streams through
 VMEM in (BLOCK_S, D) tiles with an online softmax.  This is the hot loop
 of decode_32k / long_500k serving.
 
-Validated on CPU with interpret=True against ref.decode_attention_ref.
+Validated on CPU in interpret mode against ref.decode_attention_ref; not yet compiled
+for a TPU.
 """
 from __future__ import annotations
 
@@ -51,7 +52,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, seq_k, block_s):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_s"))
-def decode_attention(q, k, v, *, scale=None, interpret: bool = True,
+def decode_attention(q, k, v, *, scale=None, interpret: bool,
                      block_s: int = BLOCK_S):
     """q:(B,H,D) one new token; k/v:(B,S,KV,D) cache -> (B,H,D)."""
     B, H, D = q.shape

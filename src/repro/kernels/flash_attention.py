@@ -6,7 +6,8 @@ softmax (running max / sum), so VMEM holds O(BLOCK_Q x BLOCK_K) scores
 instead of the O(T x S) full matrix.  Block sizes are multiples of 128 so
 the QK^T and PV matmuls land on MXU-aligned shapes; accumulation is f32.
 
-Validated on CPU with interpret=True against ref.flash_attention_ref.
+Validated on CPU in interpret mode against ref.flash_attention_ref; not yet compiled
+for a TPU.
 """
 from __future__ import annotations
 
@@ -69,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, seq_k,
 @functools.partial(jax.jit, static_argnames=("causal", "interpret",
                                              "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True, scale=None,
-                    interpret: bool = True, block_q: int = BLOCK_Q,
+                    interpret: bool, block_q: int = BLOCK_Q,
                     block_k: int = BLOCK_K):
     """q:(B,H,T,D) k/v:(B,H,S,D) -> (B,H,T,D)."""
     B, H, T, D = q.shape

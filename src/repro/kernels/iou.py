@@ -7,7 +7,8 @@ the natural (N, 4) layout would waste 124/128 lanes per vector op.
 Tiling: grid (N/BN, M/BM); each program computes a (BN, BM) IoU tile from
 one (4, BN) and one (4, BM) strip held in VMEM.
 
-Validated on CPU with interpret=True against ref.iou_matrix_ref.
+Validated on CPU in interpret mode against ref.iou_matrix_ref; not yet compiled
+for a TPU.
 """
 from __future__ import annotations
 
@@ -39,7 +40,7 @@ def _iou_kernel(a_ref, b_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_n",
                                              "block_m"))
-def iou_matrix(a, b, *, interpret: bool = True, block_n: int = BLOCK_N,
+def iou_matrix(a, b, *, interpret: bool, block_n: int = BLOCK_N,
                block_m: int = BLOCK_M):
     """a:(N,4) b:(M,4) xyxy -> (N,M) f32 IoU (N, M padded internally)."""
     N, M = a.shape[0], b.shape[0]

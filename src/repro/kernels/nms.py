@@ -31,9 +31,10 @@ it with one launch per micro-batch that is exact (bit-compatible with
     can never suppress a positive-score box (they sort after all of
     them) and the detector masks them out of ``valid`` anyway.
  5. **Slot assignment**: survivor i lands in output slot
-    ``#survivors-before-i`` — an O(A) exclusive cumsum over the alive
-    mask (a dense triangular-matrix product would put an (A, A) operand
-    back into VMEM, exactly what the tiling avoids).
+    ``#survivors-before-i``, counted tile by tile as the survivors
+    found before the tile plus the tile's own survivors ranked inside
+    it (a (tile, A) masked sum — no (A, A) operand in VMEM, and no
+    ``cumsum``, which Mosaic does not lower).
 
 Greedy-equivalence of the fixpoint: ``alive[j]`` depends only on
 ``alive[i]`` for candidates i that precede j in score order, so by
@@ -45,9 +46,12 @@ Layout: boxes are carried transposed as (4, A) coordinate planes per
 frame (same trick as ``iou.py``) so the candidate index lands on the
 128-wide lane dimension; the grid has a leading batch dimension, one
 program per frame, so a whole micro-batch is suppressed in one launch.
+Per-frame blocks keep a singleton middle dimension so their last two
+dimensions equal the array's, as Mosaic's tiling rule requires.
 
-On TPU the ``pallas_call`` compiles to Mosaic; on the CPU host it runs
-in interpret mode, which validates numerics but interprets the kernel
+On TPU the ``pallas_call`` compiles to Mosaic (``chip_smoke.py`` checks
+it against the XLA twin on the chip); on the CPU host it runs in
+interpret mode, which validates numerics but interprets the kernel
 body per grid step.  ``batched_nms_xla`` is the same algorithm written
 as batched XLA ops (tiles unrolled, per-tile early exit via
 ``lax.cond``) and is the fast path on non-TPU hosts — see
@@ -66,109 +70,120 @@ DEFAULT_TILE = 32
 
 
 def _plane_iou(tx0, ty0, tx1, ty1, tarea, x0, y0, x1, y1, area):
-    """IoU of a (T,) tile of boxes against (A,) boxes -> (T, A)."""
-    ix0 = jnp.maximum(tx0[:, None], x0[None, :])
-    iy0 = jnp.maximum(ty0[:, None], y0[None, :])
-    ix1 = jnp.minimum(tx1[:, None], x1[None, :])
-    iy1 = jnp.minimum(ty1[:, None], y1[None, :])
+    """IoU of a (T, 1) column tile of boxes against (1, A) lane planes
+    -> (T, A)."""
+    ix0 = jnp.maximum(tx0, x0)
+    iy0 = jnp.maximum(ty0, y0)
+    ix1 = jnp.minimum(tx1, x1)
+    iy1 = jnp.minimum(ty1, y1)
     inter = jnp.clip(ix1 - ix0, 0.0) * jnp.clip(iy1 - iy0, 0.0)
-    union = tarea[:, None] + area[None, :] - inter
+    union = tarea + area - inter
     # degenerate zero-area boxes (e.g. padding rows): union == inter == 0
     # -> IoU 0, never NaN
     return inter / jnp.maximum(union, 1e-9)
 
 
-def _intra_tile_fixpoint(intra_sup, pre):
-    """Greedy NMS inside one tile: ``intra_sup`` (T, T) is the strictly
-    upper-triangular suppression relation in score order, ``pre`` (T,)
-    the candidates still alive after earlier tiles."""
+def _nms_kernel(boxes_ref, rows_ref, scores_ref, oidx_ref, keep_ref,
+                valid_ref, *, n_real, iou_thr, score_thr, max_out, tile,
+                num_candidates, stop_at_zero):
+    """One grid program = one frame of the micro-batch.
 
-    def cond(state):
-        alive, prev, it = state
-        return (it == 0) | jnp.any(alive != prev)
-
-    def body(state):
-        alive, _, it = state
-        new = pre & ~jnp.any(intra_sup & alive[:, None], axis=0)
-        return new, alive, it + 1
-
-    alive, _, _ = jax.lax.while_loop(cond, body, (pre, pre, 0))
-    return alive
-
-
-def _nms_kernel(boxes_ref, scores_ref, oidx_ref, keep_ref, count_ref, *,
-                n_real, iou_thr, score_thr, max_out, tile, num_candidates,
-                stop_at_zero):
-    """One grid program = one frame of the micro-batch."""
-    b = boxes_ref[0].astype(jnp.float32)             # (4, Ap) planes
-    x0, y0, x1, y1 = b[0], b[1], b[2], b[3]
+    Everything stays 2-D and lane-major, which is what Mosaic lowers:
+    per-candidate state is a (1, Ap) lane vector holding 0.0 / 1.0, and
+    the current tile's boxes are (tile, 1) columns read from the (Ap, 4)
+    row copy of the boxes with ``pl.ds``.  The alive state of a tile is
+    turned into a column by a masked lane reduction against an iota
+    plane, not a dynamic slice."""
+    f32 = jnp.float32
+    b = boxes_ref[0].astype(f32)                     # (4, Ap) planes
+    x0, y0, x1, y1 = b[0:1], b[1:2], b[2:3], b[3:4]  # (1, Ap) each
     area = (x1 - x0) * (y1 - y0)
-    s = scores_ref[0].astype(jnp.float32)            # (Ap,) sorted desc
+    s = scores_ref[0].astype(f32)                    # (1, Ap) sorted desc
     if score_thr is not None:
         s = jnp.where(s >= score_thr, s, 0.0)
-    Ap = s.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, Ap), 1)[0]
+    Ap = s.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, Ap), 1)
+    lane_t = jax.lax.broadcasted_iota(jnp.int32, (tile, Ap), 1)
+    row_t = jax.lax.broadcasted_iota(jnp.int32, (tile, Ap), 0)
+
+    def column(v, c0):
+        """Lanes [c0, c0 + tile) of the (1, Ap) vector ``v`` as a
+        (tile, 1) column (exactly one term of each sum is non-zero)."""
+        return jnp.sum(jnp.where(lane_t == c0 + row_t, v, 0.0), axis=1,
+                       keepdims=True)
 
     n_cand = min(n_real, num_candidates)
     n_tiles = pl.cdiv(n_cand, tile)
-    alive0 = lane < n_cand                           # padding never alive
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0) <
-           jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1))
+    alive0 = jnp.where(lane < n_cand, 1.0, 0.0)      # padding never alive
+    slot0 = jnp.full((1, Ap), -1.0, f32)             # output slot per lane
 
     def tile_cond(state):
-        t, alive, found = state
+        t, _, _, found = state
         more = (t < n_tiles) & (found < max_out)
         if stop_at_zero:
-            tile_best = jax.lax.dynamic_slice(s, (t * tile,), (1,))[0]
+            tile_best = jnp.sum(jnp.where(lane == t * tile, s, 0.0))
             more &= tile_best > 0.0
         return more
 
     def tile_body(state):
-        t, alive, found = state
+        t, alive, slot, found = state
         c0 = t * tile
-        tx0 = jax.lax.dynamic_slice(x0, (c0,), (tile,))
-        ty0 = jax.lax.dynamic_slice(y0, (c0,), (tile,))
-        tx1 = jax.lax.dynamic_slice(x1, (c0,), (tile,))
-        ty1 = jax.lax.dynamic_slice(y1, (c0,), (tile,))
-        ta = jax.lax.dynamic_slice(area, (c0,), (tile,))
+        tb = rows_ref[0, pl.ds(pl.multiple_of(c0, tile), tile), :]
+        tb = tb.astype(f32)                                    # (T, 4)
+        tx0, ty0, tx1, ty1 = (tb[:, k:k + 1] for k in range(4))
+        ta = (tx1 - tx0) * (ty1 - ty0)
         sup = _plane_iou(tx0, ty0, tx1, ty1, ta,
                          x0, y0, x1, y1, area) >= iou_thr      # (T, Ap)
-        intra = jax.lax.dynamic_slice(sup, (0, c0), (tile, tile)) & tri
-        pre = jax.lax.dynamic_slice(alive, (c0,), (tile,))
-        a_c = _intra_tile_fixpoint(intra, pre)
+        in_tile = (lane >= c0) & (lane < c0 + tile)
+        # strictly-later candidates of the same tile, in score order
+        intra = sup & (lane_t > c0 + row_t) & (lane_t < c0 + tile)
+        pre = jnp.where(in_tile, alive, 0.0)
+
+        def fix_cond(st):
+            a, prev, it = st
+            return (it == 0) | (jnp.max(jnp.abs(a - prev)) > 0.0)
+
+        def fix_body(st):
+            a, _, it = st
+            hit = jnp.max(jnp.where(intra & (column(a, c0) > 0.0), 1.0,
+                                    0.0), axis=0, keepdims=True)
+            return pre * (1.0 - hit), a, it + 1
+
+        a_c, _, _ = jax.lax.while_loop(fix_cond, fix_body, (pre, pre, 0))
+        a_col = column(a_c, c0) > 0.0                          # (T, 1)
         # one vectorized pass suppresses every later candidate
-        later = lane[None, :] >= c0 + tile
-        dead_later = jnp.any(sup & later & a_c[:, None], axis=0)
-        alive = alive & ~dead_later
-        alive = jax.lax.dynamic_update_slice(alive, a_c, (c0,))
-        return t + 1, alive, found + jnp.sum(a_c.astype(jnp.int32))
+        dead = jnp.max(jnp.where(sup & (lane_t >= c0 + tile) & a_col, 1.0,
+                                 0.0), axis=0, keepdims=True)
+        alive = jnp.where(in_tile, a_c, alive * (1.0 - dead))
+        # survivor j of this tile lands in slot found + #survivors of the
+        # tile before it
+        rank = jnp.sum(jnp.where((lane_t > c0 + row_t) & a_col, 1.0, 0.0),
+                       axis=0, keepdims=True)
+        slot = jnp.where(in_tile & (a_c > 0.0), found + rank, slot)
+        return t + 1, alive, slot, found + jnp.sum(a_c)
 
-    _, alive, found = jax.lax.while_loop(
-        tile_cond, tile_body, (0, alive0, jnp.int32(0)))
+    _, _, slot, _ = jax.lax.while_loop(
+        tile_cond, tile_body, (0, alive0, slot0, jnp.float32(0.0)))
 
-    # slot[i] = number of survivors before i (exclusive cumsum; O(A),
-    # unlike a dense triangular-matrix product which would put an
-    # (Ap, Ap) operand back into VMEM)
-    alive_i = alive.astype(jnp.int32)
-    slot = jnp.cumsum(alive_i) - alive_i
-    mo = keep_ref.shape[1]
-    slot_iota = jax.lax.broadcasted_iota(jnp.int32, (Ap, mo), 1)
-    onehot = (alive[:, None] & (slot[:, None] == slot_iota))
-    oidx = oidx_ref[0].astype(jnp.int32)
-    keep_ref[0, :] = jnp.sum(
-        jnp.where(onehot, oidx[:, None], 0), axis=0).astype(jnp.int32)
-    count_ref[0, 0] = jnp.minimum(found, max_out)
+    # slots past max_out are dropped (the reference's out-of-bounds
+    # scatter); an unfilled slot reads index 0 and is not valid
+    k = jax.lax.broadcasted_iota(jnp.int32, (max_out, Ap), 0).astype(f32)
+    onehot = slot == k                                     # (max_out, Ap)
+    keep_ref[0] = jnp.sum(jnp.where(onehot, oidx_ref[0], 0), axis=1,
+                          keepdims=True)
+    valid_ref[0] = jnp.max(jnp.where(onehot, 1, 0), axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "iou_thr", "score_thr", "max_out", "tile", "num_candidates",
     "stop_at_zero", "interpret"))
-def batched_nms_pallas(boxes, scores, *, iou_thr=0.5, score_thr=None,
-                       max_out=64, tile=DEFAULT_TILE, num_candidates=None,
-                       stop_at_zero=False, interpret=True):
+def batched_nms_pallas(boxes, scores, *, interpret, iou_thr=0.5,
+                       score_thr=None, max_out=64, tile=DEFAULT_TILE,
+                       num_candidates=None, stop_at_zero=False):
     """boxes (B, A, 4) xyxy, scores (B, A) -> keep (B, max_out) int32,
     valid (B, max_out) bool.  Exact greedy NMS per frame, one launch for
-    the whole micro-batch."""
+    the whole micro-batch.  ``interpret`` has no default: the caller
+    (``ops.batched_nms``) decides from the platform."""
     B, A = scores.shape
     if num_candidates is None:
         num_candidates = A
@@ -181,40 +196,43 @@ def batched_nms_pallas(boxes, scores, *, iou_thr=0.5, score_thr=None,
     ss = jnp.take_along_axis(scores.astype(jnp.float32), order, axis=1)
 
     # pad to a common multiple of the tile and the 8-sublane minimum so
-    # the last tile's dynamic_slice never clamps (a clamped start would
-    # re-process — and double-count — earlier candidates)
+    # every tile lies inside the block
     pad = -A % math.lcm(tile, 8)
     if pad:
         bs = jnp.pad(bs, ((0, 0), (0, pad), (0, 0)))
         ss = jnp.pad(ss, ((0, 0), (0, pad)), constant_values=-jnp.inf)
         order = jnp.pad(order, ((0, 0), (0, pad)))
     Ap = A + pad
-    bt = bs.transpose(0, 2, 1)                       # (B, 4, Ap) planes
+    # (4, Ap) planes put the candidates on the lanes; the (Ap, 4) rows
+    # hand the kernel each tile's boxes as sublane columns
+    bt = bs.transpose(0, 2, 1)
 
     kernel = functools.partial(
         _nms_kernel, n_real=A, iou_thr=iou_thr, score_thr=score_thr,
         max_out=max_out, tile=tile, num_candidates=num_candidates,
         stop_at_zero=stop_at_zero)
-    keep, count = pl.pallas_call(
+    # per-frame blocks keep a singleton middle dim, so the last two
+    # block dims equal the array's (Mosaic's (8, 128) tiling rule)
+    keep, valid = pl.pallas_call(
         kernel,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, 4, Ap), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, Ap), lambda b: (b, 0)),
-            pl.BlockSpec((1, Ap), lambda b: (b, 0)),
+            pl.BlockSpec((1, Ap, 4), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, 1, Ap), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, 1, Ap), lambda b: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, max_out), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
+            pl.BlockSpec((1, max_out, 1), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, max_out, 1), lambda b: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, max_out), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, max_out, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, max_out, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(bt, ss, order.astype(jnp.int32))
-    valid = jnp.arange(max_out)[None, :] < count
-    return keep, valid
+    )(bt, bs, ss[:, None, :], order.astype(jnp.int32)[:, None, :])
+    return keep[..., 0], valid[..., 0] > 0
 
 
 def _pair_iou(a, b):

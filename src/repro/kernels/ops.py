@@ -1,8 +1,9 @@
 """Jit'd dispatch layer over the Pallas kernels.
 
-On the CPU host the kernels execute in interpret mode (the kernel body
-runs as traced JAX ops — numerics identical to TPU); on a TPU backend the
-same pallas_call compiles to Mosaic.  ``use_pallas=False`` falls back to
+``_interpret()`` is the one place that decides how a Pallas kernel
+runs: compiled by Mosaic on a TPU backend, interpreted anywhere else
+(the kernel body runs as traced JAX ops, which checks results, not
+speed).  ``use_pallas=False`` falls back to
 the pure-jnp oracles in ref.py (the default inside model code, where XLA
 fusion already does well; benchmarks compare both paths).
 
@@ -35,6 +36,7 @@ from .roi import uncrop_boxes_xla as _uncrop_xla
 
 
 def _interpret() -> bool:
+    """True unless the default backend is a TPU."""
     return jax.default_backend() != "tpu"
 
 

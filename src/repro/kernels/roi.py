@@ -8,9 +8,10 @@ halves of that data movement live here:
 * ``crop_resize_pallas`` — nearest-neighbor crop+resize of R normalized
   xyxy windows per frame into fixed (C, C) tiles, so ROI crops slot
   straight into the existing micro-batch path.  The gather is expressed
-  as two one-hot matmuls (rows then columns): the source-index
-  comparison against a ``broadcasted_iota`` builds a (C, H) / (C, W)
-  selection matrix, and the contraction runs on the MXU — no serial
+  as two one-hot matmuls per channel plane (rows then columns): the
+  source-index comparison against a ``broadcasted_iota`` builds a
+  (C, H) / (W, C) selection matrix, and the contraction runs on the MXU
+  at ``Precision.HIGHEST`` (exact for one-hot operands) — no serial
   per-pixel gather loop in the kernel body.  Grid (B, R): one program
   per window.
 * ``uncrop_boxes_pallas`` — maps second-pass detections from crop pixel
@@ -30,8 +31,8 @@ uncrop, Pallas and the XLA twin are bit-identical to each other, and
 both match the numpy oracle to within one float32 ULP of the parent
 frame scale — XLA contracts the ``r0 + t * (r1 - r0)`` pattern into an
 FMA inside jit, which eager numpy cannot express.  Validated on CPU
-with interpret=True against ``ref.crop_resize_ref`` /
-``ref.uncrop_boxes_ref``.
+in interpret mode against ``ref.crop_resize_ref`` /
+``ref.uncrop_boxes_ref``, and on the chip by ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -45,51 +46,54 @@ UNCROP_BLOCK = 128
 
 
 def _crop_kernel(img_ref, roi_ref, o_ref, *, H, W, ch, C):
-    img = img_ref[0].astype(jnp.float32)         # (H, W*ch)
-    roi = roi_ref[...].astype(jnp.float32)       # (1, 1, 4)
-    x0, y0 = roi[0, 0, 0], roi[0, 0, 1]
-    x1, y1 = roi[0, 0, 2], roi[0, 0, 3]
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST   # one-hot selection must stay exact
+    roi = roi_ref[0, 0].astype(f32)              # (1, 4)
+    x0, y0, x1, y1 = (roi[:, k:k + 1] for k in range(4))   # (1, 1) each
     # rows: out row i reads src row floor((y0 + (i+.5)/C*(y1-y0)) * H)
-    ii = jax.lax.broadcasted_iota(jnp.float32, (C, H), 0)
-    hh = jax.lax.broadcasted_iota(jnp.float32, (C, H), 1)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (C, H), 0).astype(f32)
+    hh = jax.lax.broadcasted_iota(jnp.int32, (C, H), 1).astype(f32)
     fy = (ii + 0.5) / C
     ys = jnp.clip(jnp.floor((y0 + fy * (y1 - y0)) * H), 0.0, H - 1.0)
-    row_oh = (hh == ys).astype(jnp.float32)      # (C, H) one-hot
-    rows = jnp.dot(row_oh, img).reshape(C, W, ch)
-    # columns: same selection along x as a second one-hot contraction
-    jj = jax.lax.broadcasted_iota(jnp.float32, (C, W), 0)
-    ww = jax.lax.broadcasted_iota(jnp.float32, (C, W), 1)
+    row_oh = (hh == ys).astype(f32)              # (C, H) one-hot
+    # columns: out column j reads src column xs[j], as a (W, C) one-hot
+    ww = jax.lax.broadcasted_iota(jnp.int32, (W, C), 0).astype(f32)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (W, C), 1).astype(f32)
     fx = (jj + 0.5) / C
     xs = jnp.clip(jnp.floor((x0 + fx * (x1 - x0)) * W), 0.0, W - 1.0)
-    col_oh = (ww == xs).astype(jnp.float32)      # (C, W) one-hot
-    out = jnp.einsum("cwk,dw->cdk", rows, col_oh)
-    o_ref[...] = out.reshape(1, 1, C, C * ch)
+    col_oh = (ww == xs).astype(f32)              # (W, C) one-hot
+    for k in range(ch):                          # one (H, W) plane each
+        rows = jnp.dot(row_oh, img_ref[0, k].astype(f32), precision=hi,
+                       preferred_element_type=f32)          # (C, W)
+        o_ref[0, 0, k] = jnp.dot(rows, col_oh, precision=hi,
+                                 preferred_element_type=f32)
 
 
 @functools.partial(jax.jit, static_argnames=("out_size", "interpret"))
-def crop_resize_pallas(images, rois, *, out_size: int,
-                       interpret: bool = True):
+def crop_resize_pallas(images, rois, *, out_size: int, interpret: bool):
     """images (B, H, W, ch), rois (B, R, 4) normalized xyxy in [0, 1]
     -> crops (B, R, C, C, ch) float32, C = out_size.  Degenerate
     (zero-area) windows produce a constant tile of source pixel (0, 0);
-    callers mask invalid windows downstream."""
+    callers mask invalid windows downstream.  ``interpret`` has no
+    default: the caller (``ops.crop_resize``) decides from the
+    platform."""
     B, H, W, ch = images.shape
     R = rois.shape[1]
     C = out_size
-    flat = images.reshape(B, H, W * ch)
+    planes = images.transpose(0, 3, 1, 2)        # (B, ch, H, W)
     out = pl.pallas_call(
         functools.partial(_crop_kernel, H=H, W=W, ch=ch, C=C),
         grid=(B, R),
         in_specs=[
-            pl.BlockSpec((1, H, W * ch), lambda b, r: (b, 0, 0)),
-            pl.BlockSpec((1, 1, 4), lambda b, r: (b, r, 0)),
+            pl.BlockSpec((1, ch, H, W), lambda b, r: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 4), lambda b, r: (b, r, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, C, C * ch),
-                               lambda b, r: (b, r, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, R, C, C * ch), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, ch, C, C),
+                               lambda b, r: (b, r, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, R, ch, C, C), jnp.float32),
         interpret=interpret,
-    )(flat, rois.astype(jnp.float32))
-    return out.reshape(B, R, C, C, ch)
+    )(planes, rois.astype(jnp.float32)[:, :, None, :])
+    return out.transpose(0, 1, 3, 4, 2)
 
 
 @functools.partial(jax.jit, static_argnames=("out_size",))
@@ -128,7 +132,7 @@ def _uncrop_kernel(b_ref, r_ref, o_ref, *, W, H, C):
 @functools.partial(jax.jit, static_argnames=("bounds", "crop_size",
                                              "interpret", "block"))
 def uncrop_boxes_pallas(boxes, rois, *, bounds, crop_size: int,
-                        interpret: bool = True, block: int = UNCROP_BLOCK):
+                        interpret: bool, block: int = UNCROP_BLOCK):
     """boxes (..., 4) xyxy in crop pixel coordinates [0, crop_size],
     rois (..., 4) normalized parent windows (broadcast against the
     boxes' leading shape) -> boxes in parent-frame pixel coordinates,

@@ -10,7 +10,8 @@ scratch persists across T-chunks (standard TPU accumulation pattern; the
 chunk bounds VMEM at CHUNK_T x hs per input).  hs = 64 keeps the per-head
 state (64x64 f32 = 16 KB) resident.
 
-Validated on CPU with interpret=True against ref-equivalent jnp scan.
+Validated on CPU in interpret mode against a ref-equivalent jnp scan;
+not yet compiled for a TPU.
 """
 from __future__ import annotations
 
@@ -54,7 +55,7 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sf_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "chunk_t"))
-def rwkv_scan(r, k, v, w, u, s0, *, interpret: bool = True,
+def rwkv_scan(r, k, v, w, u, s0, *, interpret: bool,
               chunk_t: int = CHUNK_T):
     """r/k/v/w: (B, H, T, hs); u: (H, hs); s0: (B, H, hs, hs).
     Returns (out (B,H,T,hs), s_final (B,H,hs,hs))."""

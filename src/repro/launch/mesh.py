@@ -6,27 +6,36 @@ jax init)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the sharding hooks
+    (``sharding.context.constrain``) place activations with
+    ``with_sharding_constraint``, which accepts only Auto mesh axes."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_replica_mesh(n_replicas: int, chips_per_replica: int = 1):
     """Paper-mode mesh: n parallel detection-model replicas over the
     ``replica`` axis (the paper's n NCS2 sticks), each replica spanning
     ``chips_per_replica`` model-parallel chips."""
-    return jax.make_mesh((n_replicas, chips_per_replica),
+    return _mesh((n_replicas, chips_per_replica),
                          ("data", "model"))
 
 
 def make_host_mesh():
     """Single-host CPU mesh for smoke runs."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _mesh((n, 1), ("data", "model"))
 
 
 def make_serving_mesh(n_shards: int | None = None):
@@ -47,4 +56,4 @@ def make_serving_mesh(n_shards: int | None = None):
             "are visible; set XLA_FLAGS=--xla_force_host_platform_"
             "device_count before the first jax import for CPU smoke "
             "meshes")
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _mesh((n, 1), ("data", "model"))

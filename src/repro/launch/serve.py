@@ -7,9 +7,9 @@ via dryrun.py).
 
 ``--payload frames`` switches to the detection/NVR path: a synthetic
 multi-camera trace served by ``ShardedDetectionEngine`` on a
-``make_serving_mesh`` host mesh (``--shards`` > available devices falls
-back to the meshless Python partition with a warning; force devices
-with XLA_FLAGS=--xla_force_host_platform_device_count=N).
+``make_serving_mesh`` host mesh when ``--spmd`` is given (one device
+per shard, or the launcher exits with an error; on a CPU host force
+devices with XLA_FLAGS=--xla_force_host_platform_device_count=N).
 
   PYTHONPATH=src python -m repro.launch.serve --payload frames \\
       --shards 2 --cameras 8 --frames 24
@@ -37,12 +37,12 @@ def serve_frames(args):
         args.cameras, args.frames, args.rate)
     mesh = None
     if args.spmd:
-        if args.shards <= len(jax.devices()):
-            mesh = make_serving_mesh(args.shards)
-        else:
-            print(f"# {args.shards} shards > {len(jax.devices())} devices: "
-                  "meshless fallback (set XLA_FLAGS=--xla_force_host_"
-                  "platform_device_count to get a real mesh)")
+        if args.shards > len(jax.devices()):
+            raise SystemExit(
+                f"--spmd needs one device per shard: {args.shards} shards "
+                f"but {len(jax.devices())} devices (on a CPU host, set "
+                "XLA_FLAGS=--xla_force_host_platform_device_count)")
+        mesh = make_serving_mesh(args.shards)
     kw = dict(n_shards=args.shards, n_replicas=args.n_replicas,
               scheduler=args.scheduler, track_and_interpolate=True,
               recorder=recorder)
@@ -204,4 +204,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

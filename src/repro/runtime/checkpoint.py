@@ -16,11 +16,9 @@ import numpy as np
 
 
 def _flatten(tree):
-    # jax.tree_util spelling: jax.tree.flatten_with_path only exists in
-    # newer jax releases than this container ships
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     return {jax.tree_util.keystr(kp): np.asarray(x) for kp, x in flat}, \
-        jax.tree.structure(tree)
+        treedef
 
 
 def save_checkpoint(path: str | Path, tree: Any, step: int = 0):
@@ -45,14 +43,14 @@ def restore_checkpoint(path: str | Path, like: Any,
     ``shardings`` when given (mesh-sharded restore)."""
     path = Path(path)
     data = np.load(path / "arrays.npz")
-    flat, treedef = jax.tree_util.tree_flatten_with_path(like)
+    flat, treedef = jax.tree.flatten_with_path(like)
     out = []
     for kp, ref in flat:
         key = jax.tree_util.keystr(kp)
         arr = data[key]
         assert arr.shape == tuple(ref.shape), (key, arr.shape, ref.shape)
         out.append(arr.astype(ref.dtype))
-    tree = jax.tree.unflatten(jax.tree.structure(like), out)
+    tree = jax.tree.unflatten(treedef, out)
     if shardings is not None:
         tree = jax.device_put(tree, shardings)
     return tree

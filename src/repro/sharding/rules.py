@@ -91,11 +91,7 @@ def _spec_for(path_str: str, leaf, mesh: Mesh, rules) -> P:
 
 
 def _path_str(kp) -> str:
-    try:
-        return keystr(kp, simple=True, separator="/")
-    except TypeError:  # older jax: render and strip the [''] decorations
-        return keystr(kp).replace("']['", "/").strip("[']").replace("[", "/") \
-            .replace("]", "")
+    return keystr(kp, simple=True, separator="/")
 
 
 def param_specs(params, mesh: Mesh):

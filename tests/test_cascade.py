@@ -180,7 +180,8 @@ def test_crop_resize_three_tiers_bit_compatible():
     images, rois = _roi_fixture()
     ref = np.asarray(crop_resize_ref(images, rois, out_size=8))
     xla = np.asarray(crop_resize_xla(images, rois, out_size=8))
-    pal = np.asarray(crop_resize_pallas(images, rois, out_size=8))
+    pal = np.asarray(crop_resize_pallas(images, rois, out_size=8,
+                                        interpret=True))
     # index quantization (floor/clip) absorbs the FMA contraction:
     # all three tiers agree exactly
     assert np.array_equal(ref, xla)
@@ -195,7 +196,7 @@ def test_uncrop_boxes_pallas_matches_xla_exactly():
     kw = dict(bounds=(640, 480), crop_size=16)
     xla = np.asarray(uncrop_boxes_xla(boxes, rois[:, :, None, :], **kw))
     pal = np.asarray(uncrop_boxes_pallas(boxes, rois[:, :, None, :],
-                                         **kw))
+                                         interpret=True, **kw))
     ref = uncrop_boxes_ref(boxes, rois[:, :, None, :], **kw)
     # both jitted tiers see the same FMA contraction: exact match;
     # the numpy oracle differs by at most ~1 ULP of the frame scale

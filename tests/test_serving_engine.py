@@ -90,8 +90,10 @@ def test_detection_engine_micro_batches_preserve_order():
 
 def test_detection_engine_batching_matches_per_frame_results():
     """Micro-batch size must not change detections: the batched NMS is
-    per-frame exact, so serving with mb=1 and mb=5 gives identical
-    valid-masked outputs."""
+    per-frame exact, so serving with mb=1 and mb=5 keeps the same
+    detections (``valid``, ``classes``).  Boxes and scores agree to a
+    few float32 ulps, not bit for bit: XLA's convolution does not
+    promise equal summation order across batch shapes, on CPU or TPU."""
     import jax
     from repro.detector import SSDConfig, init_ssd
     cfg = SSDConfig()
@@ -104,5 +106,8 @@ def test_detection_engine_batching_matches_per_frame_results():
         outs[mb] = eng.serve(frames)["responses"]
     for a, b in zip(outs[1], outs[5]):
         assert np.array_equal(a.valid, b.valid)
-        assert np.array_equal(a.boxes[a.valid], b.boxes[b.valid])
         assert np.array_equal(a.classes[a.valid], b.classes[b.valid])
+        np.testing.assert_allclose(a.boxes[a.valid], b.boxes[b.valid],
+                                   rtol=0, atol=1e-6)
+        np.testing.assert_allclose(a.scores[a.valid], b.scores[b.valid],
+                                   rtol=0, atol=1e-6)

@@ -169,6 +169,22 @@ def test_mesh_and_detect_fn_are_mutually_exclusive():
         ShardedDetectionEngine(mesh=object(), detect_fn=lambda i, r: None)
 
 
+def test_launcher_spmd_refuses_more_shards_than_devices():
+    """``serve --payload frames --spmd`` must fail, not quietly serve
+    on the meshless partition, when a shard would have no device."""
+    import argparse
+
+    import jax
+
+    from repro.launch.serve import serve_frames
+    args = argparse.Namespace(
+        trace=None, cameras=2, frames=2, rate=2.0, spmd=True,
+        shards=len(jax.devices()) + 1, n_replicas=1, scheduler="fcfs",
+        models=None, roi=False)
+    with pytest.raises(SystemExit, match="one device per shard"):
+        serve_frames(args)
+
+
 # ------------------------------------------------------ SPMD detect
 def test_spmd_detect_bit_identical_to_plain_jit_path():
     """``make_spmd_detect`` on a host mesh must return bit-identical
