@@ -55,6 +55,8 @@ SRC = Path(__file__).resolve().parent / "src"
 # and sigmoid scores): 1e-4 of a 64 px frame is 0.0064 px
 ATOL = 1e-4
 SCORE_THR, IOU_THR, MAX_OUT = 0.4, 0.5, 32
+# the served trace: 8 cameras x 32 frames at ETH-Sunnyday's 14 fps
+CAMERAS, FRAMES, RATE = 8, 32, 14.0
 
 
 def log(msg: str):
@@ -116,21 +118,21 @@ def match_detections(a, b, label: str):
     return worst, moved
 
 
-def make_trace(cameras: int, frames: int, rate: float, size: int):
+def make_trace(size: int):
     """The NVR trace with real rendered frames: camera ``s`` shows the
-    benchmark scene from frame ``s * frames`` on."""
+    benchmark scene from frame ``s * FRAMES`` on."""
     from repro.serving import make_nvr_streams
-    trace, frame_of, videos, _ = make_nvr_streams(cameras, frames, rate)
+    trace, frame_of, videos, _ = make_nvr_streams(CAMERAS, FRAMES, RATE)
     for f in trace:
         s, k = frame_of[f.rid]
-        f.image = videos[s].pixels(s * frames + k, size)
+        f.image = videos[s].pixels(s * FRAMES + k, size)
     return trace
 
 
-def served_trace(args, cfg, params, clog):
+def served_trace(cfg, params, clog):
     from repro.obs import TraceRecorder, audit_recorder
     from repro.serving import DetectionEngine
-    trace = make_trace(args.cameras, args.frames, args.rate, cfg.image_size)
+    trace = make_trace(cfg.image_size)
     rec = TraceRecorder()
     eng = DetectionEngine(cfg=cfg, params=params, max_micro_batch=8,
                           score_thr=SCORE_THR, iou_thr=IOU_THR,
@@ -302,12 +304,12 @@ def compare_kernels(seed: int) -> dict:
     return results
 
 
-def sharded_check(args, cfg, params, clog):
+def sharded_check(cfg, params, clog):
     import jax
     import numpy as np
     from repro.launch.mesh import make_serving_mesh
     from repro.serving import DetectionEngine, ShardedDetectionEngine
-    trace = make_trace(args.cameras, args.frames, args.rate, cfg.image_size)
+    trace = make_trace(cfg.image_size)
     # a pinned service time makes the virtual schedule -- which frames
     # are detected and which interpolated -- independent of wall time
     kw = dict(cfg=cfg, params=params, n_replicas=2, max_micro_batch=8,
@@ -358,11 +360,6 @@ def main():
                     help="4: run only the 4-shard mesh phase")
     ap.add_argument("--seed", type=int, default=0,
                     help="seed of the detector weights and kernel inputs")
-    ap.add_argument("--cameras", type=int, default=8)
-    ap.add_argument("--frames", type=int, default=32,
-                    help="frames per camera")
-    ap.add_argument("--rate", type=float, default=14.0,
-                    help="per-camera frames per second (ETH-Sunnyday)")
     args = ap.parse_args()
 
     if not (SRC / "repro").is_dir():
@@ -389,9 +386,9 @@ def main():
 
     t0 = time.perf_counter()
     if args.chips == 4:
-        sharded_check(args, cfg, params, clog)
+        sharded_check(cfg, params, clog)
     else:
-        eng, trace = served_trace(args, cfg, params, clog)
+        eng, trace = served_trace(cfg, params, clog)
         reference_check(eng, trace, cfg)
         kernel_check(args.seed)
     log(f"total: wall_s={time.perf_counter() - t0} "

@@ -38,9 +38,9 @@ def _conv_init(key, k, c_in, c_out):
 
 
 def _conv(p, x, stride=1):
-    # float32 on every backend: the TPU's default f32 conv rounds its
-    # inputs to bf16, which flips near-threshold scores and NMS
-    # decisions against the float32 reference
+    # float32 on every backend: the TPU's default f32 conv is one bf16
+    # pass, which reorders near-equal scores and changes which boxes
+    # survive NMS against the float32 reference
     y = jax.lax.conv_general_dilated(
         x, p["w"], (stride, stride), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
