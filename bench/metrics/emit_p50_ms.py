@@ -1,0 +1,7 @@
+"""Emit latency below the knee: median of (emit - due) over every frame
+of the window, detected and interpolated alike (host clock), in ms."""
+from bench.stats import percentile
+
+
+def read(ctx):
+    return percentile(ctx["emit_ms"], 50)
