@@ -147,26 +147,31 @@ def decode_detections(p, cfg: SSDConfig, images, anchors, score_thr=0.4,
     """Full inference: forward + box decode + fused batched NMS (one
     suppression launch for the whole micro-batch; Pallas kernel when
     use_pallas=True, its XLA twin otherwise).  Returns per-image
-    (boxes, scores, classes, valid)."""
-    deltas, obj, cls_logits = ssd_forward(p, cfg, images)
-    anc = jnp.asarray(anchors)
-    anc_wh = anc[:, 2:] - anc[:, :2]
-    anc_c = (anc[:, :2] + anc[:, 2:]) / 2
-    c = anc_c + deltas[..., :2] * anc_wh
-    wh = anc_wh * jnp.exp(jnp.clip(deltas[..., 2:], -4, 4))
-    boxes = jnp.concatenate([c - wh / 2, c + wh / 2], -1)   # (B,A,4)
-    scores = jax.nn.sigmoid(obj)
-    classes = jnp.argmax(cls_logits, -1)
-
-    # score-thresholding and suppression are fused into the batched NMS;
-    # stop_at_zero skips the zero-score tail, whose survivors the seed
-    # path enumerated only to mask them back out of ``valid``
-    keep, valid = kops.batched_nms(boxes, scores, iou_thr=iou_thr,
-                                   score_thr=score_thr, max_out=max_out,
-                                   stop_at_zero=True, use_pallas=use_pallas)
-    sc = jnp.where(scores >= score_thr, scores, 0.0)
-    bxk = jnp.take_along_axis(boxes, keep[..., None], axis=1)
-    sck = jnp.take_along_axis(sc, keep, axis=1)
-    clk = jnp.take_along_axis(classes, keep, axis=1)
-    valid = valid & (sck > 0)
+    (boxes, scores, classes, valid).  The three parts run under the
+    named scopes ``backbone``, ``decode`` and ``nms``, which prefix the
+    names of their ops in the compiled program."""
+    with jax.named_scope("backbone"):
+        deltas, obj, cls_logits = ssd_forward(p, cfg, images)
+    with jax.named_scope("decode"):
+        anc = jnp.asarray(anchors)
+        anc_wh = anc[:, 2:] - anc[:, :2]
+        anc_c = (anc[:, :2] + anc[:, 2:]) / 2
+        c = anc_c + deltas[..., :2] * anc_wh
+        wh = anc_wh * jnp.exp(jnp.clip(deltas[..., 2:], -4, 4))
+        boxes = jnp.concatenate([c - wh / 2, c + wh / 2], -1)  # (B,A,4)
+        scores = jax.nn.sigmoid(obj)
+        classes = jnp.argmax(cls_logits, -1)
+    with jax.named_scope("nms"):
+        # score-thresholding and suppression are fused into the batched
+        # NMS; stop_at_zero skips the zero-score tail, whose survivors
+        # the seed path enumerated only to mask them back out of ``valid``
+        keep, valid = kops.batched_nms(boxes, scores, iou_thr=iou_thr,
+                                       score_thr=score_thr, max_out=max_out,
+                                       stop_at_zero=True,
+                                       use_pallas=use_pallas)
+        sc = jnp.where(scores >= score_thr, scores, 0.0)
+        bxk = jnp.take_along_axis(boxes, keep[..., None], axis=1)
+        sck = jnp.take_along_axis(sc, keep, axis=1)
+        clk = jnp.take_along_axis(classes, keep, axis=1)
+        valid = valid & (sck > 0)
     return bxk, sck, clk, valid

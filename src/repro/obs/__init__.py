@@ -1,7 +1,9 @@
 """Deterministic observability for the serving stack: frame-lifecycle
 tracing (``trace``), streaming latency histograms (``metrics``),
 Perfetto/Chrome timeline export (``export``), and trace-replay
-invariant auditing (``audit``).  See ``docs/OBSERVABILITY.md``."""
+invariant auditing (``audit``).  Wall-clock spans on the profiler's
+clock are in ``repro.obs.spans`` (not imported here: it imports JAX).
+See ``docs/OBSERVABILITY.md``."""
 from repro.obs.audit import AuditResult, audit_events, audit_recorder
 from repro.obs.export import (events_from_chrome, to_chrome_trace,
                               write_chrome_trace)

@@ -47,6 +47,7 @@ from ..core.scheduler import make_scheduler
 from ..models import init_model
 from ..models.config import ModelConfig
 from ..obs.metrics import detection_latency_keys
+from ..obs.spans import span
 from ..obs.trace import NULL_RECORDER
 from ..runtime.steps import make_decode_step, make_prefill_step
 from .pipeline import TickPipeline, bucket, chunk_size, confirmed_ids
@@ -447,10 +448,15 @@ class DetectionEngine:
             self.params = params if params is not None else init_ssd(
                 self.cfg, jax.random.PRNGKey(seed))
             self.anchors = jnp.asarray(make_anchors(self.cfg))
-            self._infer = jax.jit(lambda imgs: decode_detections(
-                self.params, self.cfg, imgs, self.anchors,
-                score_thr=score_thr, iou_thr=iou_thr, max_out=max_out,
-                use_pallas=use_pallas))
+
+            def infer(imgs):
+                return decode_detections(
+                    self.params, self.cfg, imgs, self.anchors,
+                    score_thr=score_thr, iou_thr=iou_thr, max_out=max_out,
+                    use_pallas=use_pallas)
+
+            # a named function: its XLA module is ``jit_infer``
+            self._infer = jax.jit(infer)
         else:
             self.cfg = cfg
         speeds = list(replica_speeds or [1.0] * n_replicas)
@@ -526,16 +532,27 @@ class DetectionEngine:
         results + measured wall seconds.  ``model``/``rois`` are the
         cascade hooks, forwarded only to detect_fns that declare them."""
         t0 = time.perf_counter()
-        if self._detect_fn is not None:
-            kw = {}
-            if model is not None and self._fn_takes_model:
-                kw["model"] = model
-            if rois is not None and self._fn_takes_rois:
-                kw["rois"] = rois
-            out = self._detect_fn(images, rids, **kw)
-        else:
-            out = jax.block_until_ready(self._infer(jnp.asarray(images)))
-        return tuple(np.asarray(o) for o in out), time.perf_counter() - t0
+        frames = (len(images) if rids is None
+                  else sum(r >= 0 for r in rids))
+        with span("serve.detect", frames=frames,
+                  padded=len(images) - frames):
+            if self._detect_fn is not None:
+                kw = {}
+                if model is not None and self._fn_takes_model:
+                    kw["model"] = model
+                if rois is not None and self._fn_takes_rois:
+                    kw["rois"] = rois
+                out = tuple(np.asarray(o) for o in
+                            self._detect_fn(images, rids, **kw))
+            else:
+                with span("serve.detect.put", h2d_bytes=images.nbytes):
+                    x = jnp.asarray(images)
+                with span("serve.detect.run"):
+                    out = jax.block_until_ready(self._infer(x))
+                with span("serve.detect.pull") as sp:
+                    out = tuple(np.asarray(o) for o in out)
+                    sp.set_metadata(d2h_bytes=sum(o.nbytes for o in out))
+        return out, time.perf_counter() - t0
 
     def _model_caps(self) -> Dict[str, float]:
         """Summed healthy-pool service rate (frames/s) per model name —
@@ -735,88 +752,83 @@ class DetectionEngine:
         ``rec`` attached, seeding records a ``track_import`` per
         carried stream, the export records a ``track_export`` per
         stream (both carrying ``next_id`` + confirmed ``tids`` — the
-        identity-continuity audit's evidence), and one ``stage`` timing
-        event covers the whole tracker chain."""
-        rec = NULL_RECORDER if rec is None else rec
-        cfg = self.tracker_cfg
+        identity-continuity audit's evidence)."""
         per: Dict[int, List[FrameRequest]] = {}
         for f in frames:                    # frames sorted by arrival
             per.setdefault(f.stream_id, []).append(f)
-        sids = sorted(per)
-        row = {s: b for b, s in enumerate(sids)}
-        B = len(sids)
-        pipe = TickPipeline(cfg, fused=self.fused_tick)
-        rows0 = dict(tracks0) if (self.carry_tracks and tracks0) else {}
-        state = pipe.seed(sids, rows0)
-        if rec.enabled:
-            for s in sids:
-                r0 = rows0.get(s)
-                if r0 is not None:
-                    rec.record("track_import", per[s][0].t_arrival,
-                               stream=s, next_id=int(r0["next_id"]),
-                               tids=confirmed_ids(r0, cfg))
-        by_rid = {r.rid: r for r in responses}
-        D = responses[0].boxes.shape[0] if responses else 1
-        # warm-start emit floor: when this call continues a sliced trace
-        # (epoch loop), a stream's interpolated frames are never released
-        # before anything the PREVIOUS call already emitted for it
-        emit_t = {s: emit0.get(s, 0.0) for s in sids}
         ticks = max(len(v) for v in per.values())
-        wall0 = time.perf_counter()
-        out: List[DetectionResponse] = []
-        for k in range(ticks):
-            tick = [(s, per[s][k] if k < len(per[s]) else None)
-                    for s in sids]
-            resp = {s: by_rid.get(f.rid) if f is not None else None
-                    for s, f in tick}
-            det_tid = None
-            if any(r is not None for r in resp.values()):
-                boxes = np.zeros((B, D, 4), np.float32)
-                scores = np.zeros((B, D), np.float32)
-                classes = np.zeros((B, D), np.int32)
-                valid = np.zeros((B, D), bool)
-                for s, r in resp.items():
+        with span("serve.track", ticks=ticks, streams=len(per)):
+            rec = NULL_RECORDER if rec is None else rec
+            cfg = self.tracker_cfg
+            sids = sorted(per)
+            row = {s: b for b, s in enumerate(sids)}
+            B = len(sids)
+            pipe = TickPipeline(cfg, fused=self.fused_tick)
+            rows0 = dict(tracks0) if (self.carry_tracks and tracks0) else {}
+            state = pipe.seed(sids, rows0)
+            if rec.enabled:
+                for s in sids:
+                    r0 = rows0.get(s)
+                    if r0 is not None:
+                        rec.record("track_import", per[s][0].t_arrival,
+                                   stream=s, next_id=int(r0["next_id"]),
+                                   tids=confirmed_ids(r0, cfg))
+            by_rid = {r.rid: r for r in responses}
+            D = responses[0].boxes.shape[0] if responses else 1
+            # warm-start emit floor: when this call continues a sliced trace
+            # (epoch loop), a stream's interpolated frames are never released
+            # before anything the PREVIOUS call already emitted for it
+            emit_t = {s: emit0.get(s, 0.0) for s in sids}
+            out: List[DetectionResponse] = []
+            for k in range(ticks):
+                tick = [(s, per[s][k] if k < len(per[s]) else None)
+                        for s in sids]
+                resp = {s: by_rid.get(f.rid) if f is not None else None
+                        for s, f in tick}
+                det_tid = None
+                if any(r is not None for r in resp.values()):
+                    boxes = np.zeros((B, D, 4), np.float32)
+                    scores = np.zeros((B, D), np.float32)
+                    classes = np.zeros((B, D), np.int32)
+                    valid = np.zeros((B, D), bool)
+                    for s, r in resp.items():
+                        if r is not None:
+                            b = row[s]
+                            boxes[b], scores[b] = r.boxes, r.scores
+                            classes[b], valid[b] = r.classes, r.valid
+                    state, det_tid, fout = pipe.tick(state, boxes, scores,
+                                                     classes, valid)
+                else:                           # no stream saw a detection
+                    state, fout = pipe.coast(state, det_width=D)
+                # fused mode returns the tick's output for free; the staged
+                # chain materializes it lazily, only if a drop needs it
+                coasted = (tuple(np.asarray(a) for a in fout)
+                           if fout is not None else None)
+                for s, f in tick:
+                    if f is None:
+                        continue
+                    r, b = resp[s], row[s]
                     if r is not None:
-                        b = row[s]
-                        boxes[b], scores[b] = r.boxes, r.scores
-                        classes[b], valid[b] = r.classes, r.valid
-                state, det_tid, fout = pipe.tick(state, boxes, scores,
-                                                 classes, valid)
-            else:                           # no stream saw a detection
-                state, fout = pipe.coast(state, det_width=D)
-            # fused mode returns the tick's output for free; the staged
-            # chain materializes it lazily, only if a drop needs it
-            coasted = (tuple(np.asarray(a) for a in fout)
-                       if fout is not None else None)
-            for s, f in tick:
-                if f is None:
-                    continue
-                r, b = resp[s], row[s]
-                if r is not None:
-                    r.track_ids = det_tid[b]
-                    emit_t[s] = max(emit_t[s], r.t_done)
-                    out.append(r)
-                else:
-                    if coasted is None:
-                        coasted = tuple(np.asarray(a) for a in
-                                        pipe.output(state))
-                    tb, ts, tc, tid, emit = coasted
-                    t_ready = max(emit_t[s], f.t_arrival)
-                    out.append(DetectionResponse(
-                        f.rid, tb[b], ts[b], tc[b], emit[b], -1, t_ready,
-                        t_ready, 0.0, interpolated=True,
-                        track_ids=tid[b], stream_id=s, seq=seq_of[f.rid]))
-        self._tracker_launches = pipe.launches
-        self._tracker_ticks = ticks
-        self._exported_tracks = pipe.export(state, sids)
-        if rec.enabled:
-            for s in sids:
-                rowd = self._exported_tracks[s]
-                rec.record("track_export", per[s][-1].t_arrival,
-                           stream=s, next_id=int(rowd["next_id"]),
-                           tids=confirmed_ids(rowd, cfg))
-            rec.record("stage", frames[-1].t_arrival, stage="track",
-                       launches=pipe.launches, ticks=ticks)
-            rec.sample("stage_ms_track", frames[-1].t_arrival,
-                       (time.perf_counter() - wall0) * 1e3)
-        return out
+                        r.track_ids = det_tid[b]
+                        emit_t[s] = max(emit_t[s], r.t_done)
+                        out.append(r)
+                    else:
+                        if coasted is None:
+                            coasted = tuple(np.asarray(a) for a in
+                                            pipe.output(state))
+                        tb, ts, tc, tid, emit = coasted
+                        t_ready = max(emit_t[s], f.t_arrival)
+                        out.append(DetectionResponse(
+                            f.rid, tb[b], ts[b], tc[b], emit[b], -1, t_ready,
+                            t_ready, 0.0, interpolated=True,
+                            track_ids=tid[b], stream_id=s, seq=seq_of[f.rid]))
+            self._tracker_launches = pipe.launches
+            self._tracker_ticks = ticks
+            self._exported_tracks = pipe.export(state, sids)
+            if rec.enabled:
+                for s in sids:
+                    rowd = self._exported_tracks[s]
+                    rec.record("track_export", per[s][-1].t_arrival,
+                               stream=s, next_id=int(rowd["next_id"]),
+                               tids=confirmed_ids(rowd, cfg))
+            return out

@@ -65,6 +65,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.spans import span
+
 # donation is best-effort: XLA-CPU cannot honor donated buffers and
 # would warn once per fused launch; the program is correct either way
 warnings.filterwarnings(
@@ -268,7 +270,11 @@ class TickPipeline:
     def seed(self, sids, rows0: Optional[Dict[int, dict]] = None):
         """Initial table for streams ``sids``: carried rows when given,
         fresh (== ``init_state``, bit-identical) otherwise."""
-        return build_tracker_state(rows0, sids, self.cfg)
+        with span("serve.track.seed") as sp:
+            state = build_tracker_state(rows0, sids, self.cfg)
+            carried = rows0 and any(s in rows0 for s in sids)
+            sp.set_metadata(h2d_bytes=_nbytes(state) if carried else 0)
+        return state
 
     def tick(self, state, boxes, scores, classes, valid):
         """One detection tick.  Returns ``(state, det_tid, out)`` where
@@ -276,15 +282,16 @@ class TickPipeline:
         mode and None in staged mode (ask ``output`` lazily)."""
         from .. import tracking as trk   # module attr: spy-patchable
         self.launches += 1
-        args = (jnp.asarray(boxes), jnp.asarray(scores),
-                jnp.asarray(classes), jnp.asarray(valid))
-        if self.fused:
-            state, det_tid, out = _fused_tick(
-                state, *args, self.cfg, self.use_pallas)
-            return state, np.asarray(det_tid), out
-        state, det_tid = trk.step(state, *args, self.cfg,
-                                  self.use_pallas)
-        return state, np.asarray(det_tid), None
+        host = (boxes, scores, classes, valid)
+        with span("serve.track.tick", h2d_bytes=_nbytes(host)):
+            args = tuple(jnp.asarray(a) for a in host)
+            if self.fused:
+                state, det_tid, out = _fused_tick(
+                    state, *args, self.cfg, self.use_pallas)
+                return state, np.asarray(det_tid), out
+            state, det_tid = trk.step(state, *args, self.cfg,
+                                      self.use_pallas)
+            return state, np.asarray(det_tid), None
 
     def coast(self, state, det_width: int = 1):
         """One detection-free tick.  Staged mode launches
@@ -295,16 +302,17 @@ class TickPipeline:
         tick."""
         from .. import tracking as trk   # module attr: spy-patchable
         self.launches += 1
-        if self.fused:
-            B = state.active.shape[0]
-            D = det_width
-            state, _, out = _fused_tick(
-                state, jnp.zeros((B, D, 4), jnp.float32),
-                jnp.zeros((B, D), jnp.float32),
-                jnp.zeros((B, D), jnp.int32),
-                jnp.zeros((B, D), bool), self.cfg, self.use_pallas)
-            return state, out
-        return trk.coast(state, self.cfg), None
+        with span("serve.track.tick", h2d_bytes=0):
+            if self.fused:
+                B = state.active.shape[0]
+                D = det_width
+                state, _, out = _fused_tick(
+                    state, jnp.zeros((B, D, 4), jnp.float32),
+                    jnp.zeros((B, D), jnp.float32),
+                    jnp.zeros((B, D), jnp.int32),
+                    jnp.zeros((B, D), bool), self.cfg, self.use_pallas)
+                return state, out
+            return trk.coast(state, self.cfg), None
 
     def output(self, state):
         """Confirmed-track output of the current table (staged mode's
@@ -315,7 +323,15 @@ class TickPipeline:
     def export(self, state, sids) -> Dict[int, dict]:
         """Portable per-stream rows of the final table (see
         ``export_track_rows``)."""
-        return export_track_rows(state, sids)
+        with span("serve.track.export") as sp:
+            rows = export_track_rows(state, sids)
+            sp.set_metadata(d2h_bytes=_nbytes(state))
+        return rows
+
+
+def _nbytes(arrays) -> int:
+    """Bytes of the arrays of a tuple or pytree (a span's byte count)."""
+    return sum(a.nbytes for a in jax.tree.leaves(arrays))
 
 
 # ------------------------------------------------------------- ROI stage
@@ -424,11 +440,6 @@ def roi_second_pass(eng, tick: TickState, kept, pad_b: int, rec):
                 rois=[[float(x) for x in row]
                       for row in rois[j][:n_rois[j]]],
                 bounds=[float(W), float(H)], det_extent=ext)
-        # the stage EVENT carries only virtual-clock-deterministic
-        # fields (trace bit-determinism contract); the measured wall ms
-        # goes to the sampled series, exported as a Perfetto counter
-        rec.record("stage", kept[0].t_arrival, stage="roi", frames=n)
-        rec.sample("stage_ms_roi", kept[0].t_arrival, roi_wall * 1e3)
     new_tick = tick._replace(boxes=boxes, scores=scores,
                              classes=classes, valid=valid, model=heavy)
     return new_tick, (px_roi / px_full if px_full else 0.0), roi_wall, \

@@ -58,6 +58,7 @@ import numpy as np
 
 from ..core.synchronizer import SequenceSynchronizer
 from ..obs.metrics import detection_latency_keys
+from ..obs.spans import span
 from ..obs.trace import NULL_RECORDER
 from ..sharding.serving_rules import rebalance_streams, shard_streams
 from .engine import (DetectionEngine, DetectionResponse, FrameRequest,
@@ -164,13 +165,18 @@ class _DetectionCore:
             self._process_next_batch()
 
     def _process_next_batch(self):
-        eng = self.eng
-        frames = self._queue
         i = self._qi
+        chunk = self._queue[i:i + self.eng._chunk_size(self._queue, i)]
+        self._qi += len(chunk)
+        n_dropped = len(self._dropped)
+        with span("serve.batch", frames=len(chunk)) as sp:
+            self._run_batch(chunk)
+            sp.set_metadata(dropped=len(self._dropped) - n_dropped)
+
+    def _run_batch(self, chunk):
+        eng = self.eng
         rec = eng.recorder
         seq_of = self._seq_of
-        chunk = frames[i:i + eng._chunk_size(frames, i)]
-        self._qi += len(chunk)
         model = None
         if eng.cascade is not None:
             # transprecise model selection at the batch boundary — the
@@ -206,7 +212,6 @@ class _DetectionCore:
             for f in chunk:
                 rec_enq("enqueue", f.t_arrival, rid=f.rid,
                         stream=f.stream_id, batch=self._batch_no)
-        bno = self._batch_no
         self._batch_no += 1
         kept, assigns = [], []
         if eng.drop_when_busy:
@@ -243,13 +248,6 @@ class _DetectionCore:
         (boxes, scores, classes, valid), wall = eng._detect_batch(
             images, rids=[f.rid for f in kept] + [-1] * (b - len(kept)),
             **mkw)
-        if rec.enabled:
-            # deterministic stage event + wall timing as a sampled
-            # series (events must stay bit-identical across replays)
-            rec.record("stage", chunk[0].t_arrival, stage="detect",
-                       batch=bno, frames=len(kept))
-            rec.sample("stage_ms_detect", chunk[0].t_arrival,
-                       wall * 1e3)
         # from here the batch travels as a TickState through the shared
         # stage pipeline: [ROI second pass] -> post-processor hook
         tick = TickState(boxes=boxes, scores=scores, classes=classes,
@@ -350,6 +348,17 @@ class _DetectionCore:
                                          self._emit0,
                                          tracks0=self._tracks0, rec=rec)
             interpolated = sum(r.interpolated for r in responses)
+        with span("serve.report"):
+            return self._segment_report(frames, responses, n_frames_stream,
+                                        interpolated, rec)
+
+    def _segment_report(self, frames, responses, n_frames_stream,
+                        interpolated, rec) -> Dict:
+        """The report of the open segment from its tracked responses:
+        rid-order sort, per-stream reorder + emit events, per-stream
+        stats, fault-count deltas and the latency block."""
+        eng = self.eng
+        dropped = self._dropped
         responses.sort(key=lambda r: r.rid)   # sequence synchronizer
         makespan = max((r.t_done for r in responses), default=0.0)
         # per-stream reorder + drop accounting (the per-camera view of
@@ -392,6 +401,9 @@ class _DetectionCore:
                   for i in set(fc1[key]) | set(fc0[key])
                   if fc1[key].get(i, 0) - fc0[key].get(i, 0)}
             for key in ("retries", "failovers", "frames_lost")}
+        with span("serve.report.latency"):
+            latency = detection_latency_keys(
+                responses, {f.rid: f.t_arrival for f in frames})
         return {
             "responses": responses,
             "dropped": [f.rid for f in dropped],
@@ -419,8 +431,7 @@ class _DetectionCore:
                 self._switches, self._roi_px, len(frames)),
             # latency distribution block (repro.obs.metrics): exact p50
             # plus histogram-derived p95/p99 and mergeable rollups
-            **detection_latency_keys(
-                responses, {f.rid: f.t_arrival for f in frames}),
+            **latency,
         }
 
     # -------------------------------------------------------- boundaries
@@ -429,7 +440,8 @@ class _DetectionCore:
         start a new segment with the seq / emit floors carried (the
         virtual clock is NOT reset — exactly the warm-started epoch
         calls the sharded loop always made)."""
-        self.advance(_INF)
+        with span("serve.flush"):
+            self.advance(_INF)
         rep = self._finalize_segment(record=True)
         self._epoch_reports.append(rep)
         self._all_frames.extend(self._queue)
@@ -1012,7 +1024,8 @@ class ServingRuntime:
         """Feed one ``FrameRequest`` or a sequence of them.  Chunks must
         be non-decreasing in ``t_arrival`` across calls (ties allowed);
         within a chunk frames are sorted stably, like the batch path."""
-        self._core.ingest(frames)
+        with span("serve.ingest"):
+            self._core.ingest(frames)
 
     def advance(self, to_t: Optional[float] = None):
         """Run every micro-batch / epoch window that is *sealed* below
@@ -1022,7 +1035,8 @@ class ServingRuntime:
         change)."""
         if to_t is None:
             to_t = self._core._watermark
-        self._core.advance(to_t)
+        with span("serve.advance"):
+            self._core.advance(to_t)
 
     # ------------------------------------------------------------ windows
     def epoch_boundary(self):
@@ -1031,7 +1045,8 @@ class ServingRuntime:
         On the rebalancing sharded core windows are intrinsic (the
         ``epoch_s`` grid) and this returns the latest window's rollup
         instead of cutting one."""
-        return self._core.epoch_boundary()
+        with span("serve.boundary"):
+            return self._core.epoch_boundary()
 
     def report(self, rolling: bool = True):
         """Non-destructive mid-serve view.  ``rolling=True`` returns the
