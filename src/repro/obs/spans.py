@@ -44,12 +44,15 @@ SPANS = {
                    "still queued",
     "serve.track": "DetectionEngine._interpolate, the tracker over a "
                    "segment; ticks: lockstep ticks, streams: cameras",
-    "serve.track.seed": "the track table built for the segment; "
+    "serve.track.seed": "the segment's starting track table; "
+                        "resident: 1 when it is the last segment's "
+                        "device table, 0 when built from rows; "
                         "h2d_bytes: carried rows uploaded",
     "serve.track.tick": "one tracker tick or coast with its det_tid "
                         "pull; h2d_bytes: detection rows uploaded",
-    "serve.track.export": "the final table pulled into portable rows; "
-                          "d2h_bytes: bytes pulled",
+    "serve.track.export": "the final table handed on, and any later "
+                          "read of its rows; d2h_bytes: bytes pulled, "
+                          "0 while it stays on the device",
     "serve.report": "the segment's report after the tracker returns: "
                     "sort, per-stream order, stats, latency",
     "serve.report.latency": "detection_latency_keys: the latency "

@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Mapping, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +50,8 @@ from ..obs.metrics import detection_latency_keys
 from ..obs.spans import span
 from ..obs.trace import NULL_RECORDER
 from ..runtime.steps import make_decode_step, make_prefill_step
-from .pipeline import TickPipeline, bucket, chunk_size, confirmed_ids
+from .pipeline import (TickPipeline, TrackTable, bucket, chunk_size,
+                       confirmed_ids)
 
 
 @dataclass
@@ -510,7 +511,7 @@ class DetectionEngine:
         self.fused_tick = bool(fused_tick)
         self.post_process = post_process
         self.carry_tracks = bool(carry_tracks)
-        self._exported_tracks: Dict[int, dict] = {}
+        self._exported_tracks: Mapping[int, dict] = {}
         self._use_pallas = use_pallas
         # capability probe: does a custom detect_fn accept the cascade's
         # model= / rois= keywords?  A plain oracle keeps its exact
@@ -702,7 +703,9 @@ class DetectionEngine:
         ``per_stream`` ({stream_id: frames / dropped / interpolated /
         coverage / throughput_fps}), ``tracker_launches`` /
         ``tracker_ticks`` (lockstep-tracker accounting; 0 unless
-        ``track_and_interpolate``), and ``retries`` / ``failovers`` /
+        ``track_and_interpolate``), ``track_table_resident`` (segments
+        whose tracker started from the last segment's device table
+        rather than from rows), and ``retries`` / ``failovers`` /
         ``frames_lost`` (this call's failure-detection counts, sparse
         per replica — all empty on the fault-free path).
 
@@ -728,7 +731,8 @@ class DetectionEngine:
 
     def _interpolate(self, frames, responses, seq_of, emit0,
                      tracks0: Optional[Dict[int, dict]] = None,
-                     rec=None) -> List[DetectionResponse]:
+                     rec=None, resident: Optional[TrackTable] = None,
+                     ) -> List[DetectionResponse]:
         """ONE batched tracker over every camera stream, advanced in
         lockstep by the shared tick pipeline (``serving.pipeline``):
         tick k covers each stream's k-th arrival frame, and the whole
@@ -747,12 +751,16 @@ class DetectionEngine:
         delays another's output).
 
         ``tracks0`` seeds streams from carried portable rows (see
-        ``serve``'s ``stream_tracks``); the final table is exported per
-        stream into ``self._exported_tracks`` either way.  With a
-        ``rec`` attached, seeding records a ``track_import`` per
+        ``serve``'s ``stream_tracks``); ``resident``, the last
+        segment's ``TrackTable``, carries newer rows for its streams
+        and is itself the starting table when the segment serves
+        exactly its streams (``TickPipeline.seed``).  The final table
+        lands in ``self._exported_tracks`` as a ``TrackTable`` either
+        way: it stays on the device until something reads its rows.
+        With a ``rec`` attached, seeding records a ``track_import`` per
         carried stream, the export records a ``track_export`` per
         stream (both carrying ``next_id`` + confirmed ``tids`` — the
-        identity-continuity audit's evidence)."""
+        identity-continuity audit's evidence), so both read rows."""
         per: Dict[int, List[FrameRequest]] = {}
         for f in frames:                    # frames sorted by arrival
             per.setdefault(f.stream_id, []).append(f)
@@ -765,7 +773,12 @@ class DetectionEngine:
             B = len(sids)
             pipe = TickPipeline(cfg, fused=self.fused_tick)
             rows0 = dict(tracks0) if (self.carry_tracks and tracks0) else {}
-            state = pipe.seed(sids, rows0)
+            resident = resident if self.carry_tracks else None
+            if rec.enabled and resident is not None:
+                # the import events read the carried rows: pull them
+                # before a fused tick donates the table
+                rows0.update(resident)
+            state = pipe.seed(sids, rows0, resident)
             if rec.enabled:
                 for s in sids:
                     r0 = rows0.get(s)
@@ -824,7 +837,9 @@ class DetectionEngine:
                             track_ids=tid[b], stream_id=s, seq=seq_of[f.rid]))
             self._tracker_launches = pipe.launches
             self._tracker_ticks = ticks
-            self._exported_tracks = pipe.export(state, sids)
+            self._track_table_resident = pipe.resident
+            self._exported_tracks = pipe.export(state, sids,
+                                                pull=rec.enabled)
             if rec.enabled:
                 for s in sids:
                     rowd = self._exported_tracks[s]

@@ -29,6 +29,10 @@ drivers over it:
   keyed by ``stream_id`` and rebuilds with any stream subset/order, so
   track identities survive segment boundaries, ``rebalance_streams``
   migration and watchdog evacuation.
+* ``TrackTable``     — a segment's final table left on the device,
+  read as those rows only when something reads them; the next
+  segment's tracker starts from the table itself when it serves the
+  same streams.
 * ``sorted_chunk`` / ``chunk_size`` / ``bucket`` / ``dispatch_time`` —
   the chunking/ordering helpers that were copied between the batch
   engine and the incremental core.
@@ -59,6 +63,7 @@ from __future__ import annotations
 
 import functools
 import warnings
+from collections.abc import Mapping
 from typing import Dict, List, NamedTuple, Optional
 
 import jax
@@ -163,6 +168,61 @@ def export_track_rows(state, sids) -> Dict[int, dict]:
     return {s: rows[b] for b, s in enumerate(sids)}
 
 
+class TrackTable(Mapping):
+    """A segment's final (B, T) track table, left on the device, read as
+    the mapping ``stream_id -> portable row`` that ``export_track_rows``
+    makes (batch row ``b`` belongs to ``sids[b]``).
+
+    Nothing leaves the device until a row is read: the first read
+    pulls the whole table with one ``jax.device_get`` and keeps the
+    rows.  The next segment's tracker starts from the device table
+    itself when it serves the same streams in the same order
+    (``TickPipeline.seed``); ``take`` hands the table over, and a fused
+    tick then donates it, so rows not read before ``take`` can no
+    longer be read."""
+
+    def __init__(self, state, sids):
+        self.sids = tuple(sids)
+        self._state = state
+        self._rows: Optional[Dict[int, dict]] = None
+
+    @property
+    def taken(self) -> bool:
+        """Whether a later segment's tracker took the device table."""
+        return self._state is None
+
+    def take(self):
+        """The device table, for a tracker that may donate it."""
+        state, self._state = self._state, None
+        return state
+
+    def pull(self) -> int:
+        """Read the rows off the device now; returns the bytes read (0
+        when they were read already)."""
+        if self._rows is not None:
+            return 0
+        if self._state is None:
+            raise RuntimeError("the track table was handed to a later "
+                               "segment before its rows were read")
+        self._rows = export_track_rows(self._state, self.sids)
+        return _nbytes(self._state)
+
+    def __getitem__(self, sid) -> dict:
+        if self._rows is None:
+            with span("serve.track.export") as sp:
+                sp.set_metadata(d2h_bytes=self.pull())
+        return self._rows[sid]
+
+    def __contains__(self, sid) -> bool:
+        return sid in self.sids
+
+    def __iter__(self):
+        return iter(self.sids)
+
+    def __len__(self) -> int:
+        return len(self.sids)
+
+
 def build_tracker_state(rows0: Optional[Dict[int, dict]], sids, cfg):
     """Tracker table for streams ``sids`` (batch row ``b`` =
     ``sids[b]``), seeding each stream from its carried row in ``rows0``
@@ -258,7 +318,8 @@ class TickPipeline:
     program every tick, detections or not (an all-invalid row is
     bit-identical to coasting), and returns the tick's outputs for
     free.  ``launches`` counts tracker launches either way — one per
-    tick."""
+    tick; ``resident`` is 1 when ``seed`` took the last segment's
+    device table."""
 
     def __init__(self, cfg, *, use_pallas: bool = False,
                  fused: bool = False):
@@ -266,14 +327,26 @@ class TickPipeline:
         self.use_pallas = use_pallas
         self.fused = fused
         self.launches = 0
+        self.resident = 0
 
-    def seed(self, sids, rows0: Optional[Dict[int, dict]] = None):
-        """Initial table for streams ``sids``: carried rows when given,
-        fresh (== ``init_state``, bit-identical) otherwise."""
+    def seed(self, sids, rows0: Optional[Dict[int, dict]] = None,
+             resident: Optional[TrackTable] = None):
+        """Initial table for streams ``sids``.  ``resident``, the last
+        segment's ``TrackTable``, is taken as it stands when it holds
+        exactly these streams in this order (``self.resident`` becomes
+        1).  Otherwise its rows join the carried ``rows0`` and the
+        table is built from rows: carried where a stream has one, fresh
+        (== ``init_state``, bit-identical) where none has."""
         with span("serve.track.seed") as sp:
-            state = build_tracker_state(rows0, sids, self.cfg)
-            carried = rows0 and any(s in rows0 for s in sids)
-            sp.set_metadata(h2d_bytes=_nbytes(state) if carried else 0)
+            if resident is not None and resident.sids == tuple(sids):
+                self.resident = 1
+                sp.set_metadata(resident=1, h2d_bytes=0)
+                return resident.take()
+            rows = {**(rows0 or {}), **(resident or {})}
+            state = build_tracker_state(rows, sids, self.cfg)
+            carried = any(s in rows for s in sids)
+            sp.set_metadata(resident=0,
+                            h2d_bytes=_nbytes(state) if carried else 0)
         return state
 
     def tick(self, state, boxes, scores, classes, valid):
@@ -320,13 +393,14 @@ class TickPipeline:
         from .. import tracking as trk
         return trk.output(state, self.cfg)
 
-    def export(self, state, sids) -> Dict[int, dict]:
-        """Portable per-stream rows of the final table (see
-        ``export_track_rows``)."""
+    def export(self, state, sids, pull: bool = False) -> TrackTable:
+        """The final table as a ``TrackTable``: it stays on the device
+        and becomes portable rows when something reads them; ``pull``
+        reads them now."""
         with span("serve.track.export") as sp:
-            rows = export_track_rows(state, sids)
-            sp.set_metadata(d2h_bytes=_nbytes(state))
-        return rows
+            table = TrackTable(state, sids)
+            sp.set_metadata(d2h_bytes=table.pull() if pull else 0)
+        return table
 
 
 def _nbytes(arrays) -> int:

@@ -65,7 +65,7 @@ from .engine import (DetectionEngine, DetectionResponse, FrameRequest,
                      _per_replica_counts)
 from .faults import ShardFaultCursor
 from .models import cascade_report_keys
-from .pipeline import TickState, roi_second_pass
+from .pipeline import TickState, TrackTable, roi_second_pass
 from .pipeline import sorted_chunk as _sorted_chunk
 
 _INF = float("inf")
@@ -96,8 +96,13 @@ class _DetectionCore:
         # epoch core, across shard migration): stream_id -> row dict
         # from ``tracking.export_rows``.  Seeds the interpolation
         # tracker of every NEXT segment so track identities persist
-        # instead of re-seeding at epoch boundaries.
+        # instead of re-seeding at epoch boundaries.  The last
+        # segment's table stays on the device (``_resident``, a
+        # ``pipeline.TrackTable``) and carries newer rows for its
+        # streams; the next segment starts from it when it serves the
+        # same streams, and reads its rows only when it does not.
         self._tracks0: Dict[int, dict] = dict(stream_tracks or {})
+        self._resident: Optional[TrackTable] = None
         self._seq_of: Dict[int, int] = {}
         self._epoch_reports: List[Dict] = []
         self._all_frames: List[FrameRequest] = []
@@ -338,15 +343,21 @@ class _DetectionCore:
                 n_frames_stream.get(f.stream_id, 0) + 1
         interpolated = 0
         eng._tracker_launches = eng._tracker_ticks = 0
+        eng._track_table_resident = 0
         # clear stale exports up front: a segment that never runs the
         # tracker (no frames processed) must not re-offer the PREVIOUS
         # segment's table at the next boundary — the epoch core's
         # _tracks0 already holds it
         eng._exported_tracks = {}
         if eng.track_and_interpolate and (dropped or responses):
+            tracks0, resident = self._tracks0, self._resident
+            if not record and resident is not None:
+                # the peek leaves the resident table to the boundary: a
+                # tracker that took it could donate it
+                tracks0, resident = {**tracks0, **resident}, None
             responses = eng._interpolate(frames, responses, seq_of,
-                                         self._emit0,
-                                         tracks0=self._tracks0, rec=rec)
+                                         self._emit0, tracks0=tracks0,
+                                         rec=rec, resident=resident)
             interpolated = sum(r.interpolated for r in responses)
         with span("serve.report"):
             return self._segment_report(frames, responses, n_frames_stream,
@@ -417,6 +428,7 @@ class _DetectionCore:
             "per_stream": per_stream,
             "tracker_launches": eng._tracker_launches,
             "tracker_ticks": eng._tracker_ticks,
+            "track_table_resident": eng._track_table_resident,
             "retries": fault_counts["retries"],
             "failovers": fault_counts["failovers"],
             "frames_lost": fault_counts["frames_lost"],
@@ -448,10 +460,16 @@ class _DetectionCore:
         for sid, em in rep["emit_t"].items():
             if em:
                 self._emit0[sid] = max(self._emit0.get(sid, 0.0), em[-1])
-        if self.eng.carry_tracks:
+        table = self.eng._exported_tracks
+        if self.eng.carry_tracks and table:
             # track identities persist across the boundary: the closed
-            # segment's exported rows seed the next segment's tracker
-            self._tracks0.update(self.eng._exported_tracks)
+            # segment's table, left on the device, seeds the next
+            # segment's tracker.  A table the segment did not start
+            # from keeps its rows (read by that segment's seed) for
+            # the streams that left.
+            if self._resident is not None and not self._resident.taken:
+                self._tracks0.update(self._resident)
+            self._resident = table
         self._new_segment()
         return rep
 

@@ -259,7 +259,8 @@ def merge_shard_reports(frames: Sequence[FrameRequest],
     series).  The single-engine invariant "one launch per tick" thus
     reads globally as ``launches == n_shards x ticks`` — exact when
     every shard saw the same tick count (balanced frames-per-stream),
-    an upper bound on ``ticks`` otherwise."""
+    an upper bound on ``ticks`` otherwise.  ``track_table_resident``
+    sums too."""
     # renumber replica ids on COPIES (never mutate the caller's shard
     # reports), keeping the -1 tracker-interpolated sentinel; offset 0
     # (first shard / single shard) reuses the original objects so the
@@ -288,6 +289,8 @@ def merge_shard_reports(frames: Sequence[FrameRequest],
                                 for rep in reports),
         "tracker_ticks": max((rep["tracker_ticks"] for rep in reports),
                              default=0),
+        "track_table_resident": sum(rep["track_table_resident"]
+                                    for rep in reports),
         **_merged_fault_counts(reports, range(len(reports)), pool_sizes),
         **_merged_latency_keys(responses, reports, range(len(reports)),
                                pool_sizes),
@@ -331,7 +334,8 @@ def merge_epoch_shard_reports(frames: Sequence[FrameRequest],
     stream legitimately shows up on two shards).  Global
     ``tracker_launches`` sums over shards AND epochs; global
     ``tracker_ticks`` is the max over shards of each shard's summed
-    epoch ticks (shards tick in parallel, epochs in series).  The
+    epoch ticks (shards tick in parallel, epochs in series);
+    ``track_table_resident`` sums like ``tracker_launches``.  The
     caller attaches ``shard_of_stream`` / ``migrations`` /
     ``n_epochs``.
 
@@ -391,6 +395,8 @@ def merge_epoch_shard_reports(frames: Sequence[FrameRequest],
                                 for rep in reports),
         "tracker_ticks": max((sh["tracker_ticks"] for sh in per_shard),
                              default=0),
+        "track_table_resident": sum(rep["track_table_resident"]
+                                    for rep in reports),
         **_merged_fault_counts(reports, report_shard, pool_sizes),
         **_merged_latency_keys(responses, reports, report_shard,
                                pool_sizes),

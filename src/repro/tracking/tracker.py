@@ -204,12 +204,12 @@ def export_rows(state: TrackerState) -> list:
     batch axis stripped).  Rows are serializable and shard-agnostic —
     the currency track identities travel in across segment boundaries,
     stream migration and evacuation.  ``rows_to_state`` rebuilds a
-    table from any subset/reordering of them bit-identically."""
-    arrs = {f: np.asarray(getattr(state, f))
-            for f in TrackerState._fields}
-    B = arrs["active"].shape[0]
-    return [{f: arrs[f][b].copy() for f in TrackerState._fields}
-            for b in range(B)]
+    table from any subset/reordering of them bit-identically.  The
+    whole table comes off the device in one ``jax.device_get``."""
+    host = jax.device_get(state)
+    B = host.active.shape[0]
+    return [{f: np.asarray(getattr(host, f))[b].copy()
+             for f in TrackerState._fields} for b in range(B)]
 
 
 def rows_to_state(rows, cfg: TrackerConfig) -> TrackerState:

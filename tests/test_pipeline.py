@@ -8,6 +8,8 @@ engine-level (report) equivalences live in
   historical per-engine copies (delegation, not drift);
 * portable track rows round trip bit-identically (export -> rebuild,
   any subset/reordering), and an all-fresh rebuild == ``init_state``;
+* a ``TrackTable`` reads those rows only on demand, and the pipeline
+  takes it as the next table only for the same streams in order;
 * the fused one-jit tick program is bit-identical to the staged
   ``step``/``output`` chain, tick by tick, on every ``TrackerState``
   field, the per-detection track-id assignment and the output tuple;
@@ -26,9 +28,9 @@ import repro.tracking as trk
 from repro.core import proxy_detect_fn_streams
 from repro.serving import (DetectionEngine, TickPipeline, TickState,
                            make_nvr_streams)
-from repro.serving.pipeline import (bucket, build_tracker_state,
-                                    confirmed_ids, export_track_rows,
-                                    sorted_chunk)
+from repro.serving.pipeline import (TrackTable, bucket,
+                                    build_tracker_state, confirmed_ids,
+                                    export_track_rows, sorted_chunk)
 from repro.tracking import TrackerConfig
 
 CFG = TrackerConfig(capacity=16)
@@ -106,6 +108,37 @@ def test_track_rows_fresh_equals_init_state():
                           np.asarray(state.track_id[1]))
     assert np.array_equal(np.asarray(mixed.track_id[0]),
                           np.asarray(ref.track_id[0]))
+
+
+def test_track_table_reads_rows_on_demand():
+    """A ``TrackTable`` pulls nothing until a row is read, then reads
+    ``export_track_rows``' rows; ``take`` hands the device table over,
+    and rows not read before it can no longer be read."""
+    state = seeded_state()
+    sids = [7, 3, 9]
+    eager = export_track_rows(state, sids)
+    table = TrackTable(state, sids)
+    assert len(table) == 3 and list(table) == sids and 3 in table
+    assert table._rows is None            # nothing pulled yet
+    for sid in sids:
+        for f, v in eager[sid].items():
+            assert np.array_equal(table[sid][f], v), (sid, f)
+    assert table.pull() == 0              # already read
+    assert_states_equal(table.take(), state)
+    assert table.taken and table[9]["next_id"] == eager[9]["next_id"]
+    unread = TrackTable(state, sids)
+    unread.take()
+    with pytest.raises(RuntimeError, match="handed to a later segment"):
+        unread[7]
+    # the pipeline takes a table for the same streams in the same order
+    # and builds from its rows otherwise
+    pipe = TickPipeline(CFG)
+    assert pipe.seed(sids, resident=TrackTable(state, sids)) is state
+    assert pipe.resident == 1
+    pipe = TickPipeline(CFG)
+    sub = pipe.seed([9, 7], resident=TrackTable(state, sids))
+    assert pipe.resident == 0
+    assert_states_equal(sub, build_tracker_state(eager, [9, 7], CFG))
 
 
 def test_confirmed_ids_reads_the_emit_mask():

@@ -207,6 +207,178 @@ def test_sharded_rolling_rollups():
     assert sum(e["dropped"] for e in per_epoch) == 0
 
 
+# ------------------------------------------- resident track table
+def rows_every_boundary(rt):
+    """Make ``rt`` carry its track table as host rows across every
+    boundary: the row round trip the resident table replaces."""
+    core = rt._core
+    boundary = core.epoch_boundary
+
+    def epoch_boundary():
+        rep = boundary()
+        if core._resident is not None:
+            core._tracks0.update(core._resident)
+            core._resident = None
+        return rep
+
+    core.epoch_boundary = epoch_boundary
+    return rt
+
+
+def serve_segments(rt, segments, peek=False):
+    """One ``epoch_boundary`` per segment, then ``drain``; with
+    ``peek`` a ``report()`` halfway into every segment, once every
+    camera has a processed frame (so its tracker serves the resident
+    table's streams)."""
+    reps = []
+    for seg in segments:
+        half = len(seg) // 2 + 1 if peek else len(seg)
+        rt.ingest(seg[:half])
+        if peek:
+            rt.advance()
+            part = rt.report()[-1]
+            assert part["partial"]
+            assert all(v["frames"] for v in part["per_stream"].values())
+            assert rt._core._resident is None or \
+                not rt._core._resident.taken
+            rt.ingest(seg[half:])
+        reps.append(rt.epoch_boundary())
+    return reps, rt.drain()
+
+
+def without_counter(rep):
+    return {k: v for k, v in rep.items() if k != "track_table_resident"}
+
+
+def assert_segments_identical(a, b):
+    (ra, fa), (rb, fb) = a, b
+    assert len(ra) == len(rb)
+    for x, y in zip(ra, rb):
+        assert_reports_identical(without_counter(x), without_counter(y))
+    assert_reports_identical(without_counter(fa), without_counter(fb))
+
+
+def assert_rows_identical(a, b):
+    assert list(a) == list(b)
+    for sid in a:
+        for f, v in a[sid].items():
+            assert np.array_equal(v, b[sid][f]), (sid, f)
+
+
+def segments_of(frames, n_streams, per_seg):
+    """Frames (arrival order) cut into segments of ``per_seg`` frames
+    per camera: every segment serves every camera."""
+    step = per_seg * n_streams
+    return [frames[i:i + step] for i in range(0, len(frames), step)]
+
+
+@pytest.mark.parametrize("fused", (False, True))
+@pytest.mark.parametrize("rate", (1.0, 4.0), ids=("steady", "drops"))
+def test_resident_track_table_matches_row_round_trip(fused, rate):
+    frames, oracle = nvr_setup(n_streams=3, n_frames=12, rate=rate)
+    segments = segments_of(frames, 3, 2)
+    assert len(segments) >= 5
+    got_eng = det_engine(oracle, fused_tick=fused)
+    ref_eng = det_engine(oracle, fused_tick=fused)
+    got = serve_segments(ServingRuntime(got_eng), segments)
+    ref = serve_segments(rows_every_boundary(ServingRuntime(ref_eng)),
+                         segments)
+    assert_segments_identical(got, ref)
+    assert [r["track_table_resident"] for r in got[0]] == \
+        [0] + [1] * (len(segments) - 1)
+    assert [r["track_table_resident"] for r in ref[0]] == \
+        [0] * len(segments)
+    assert got[1]["track_table_resident"] == len(segments) - 1
+    interpolated = sum(r["interpolated"] for r in got[0])
+    assert (interpolated > 0) == (rate == 4.0)   # drops coast the table
+    assert any(np.any(np.asarray(r.track_ids) >= 0)
+               for r in got[1]["responses"] if r.interpolated is False)
+    assert_rows_identical(got_eng._exported_tracks,
+                          ref_eng._exported_tracks)
+
+
+class SpanLog:
+    """Stands in for ``jax.profiler.TraceAnnotation``: keeps each
+    span's name and args."""
+
+    def __init__(self):
+        self.spans = []
+
+    def __call__(self, name, **args):
+        log = self
+
+        class Span:
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                log.spans.append((name, args))
+
+            def set_metadata(self, **kw):
+                args.update(kw)
+
+        return Span()
+
+    def args(self, name):
+        return [a for n, a in self.spans if n == name]
+
+
+@pytest.mark.parametrize("fused", (False, True))
+def test_resident_track_table_falls_back_to_rows(fused, monkeypatch):
+    """A stream that has no frame in a segment (leaves), comes back,
+    or is new rebuilds the table from rows, bit-identically; the same
+    stream set takes the device table."""
+    from repro.serving import pipeline
+    frames, oracle = nvr_setup(n_streams=4, n_frames=14, rate=4.0)
+    plan = [(0, 1, 2), (0, 1, 2), (0, 1), (0, 1), (0, 1, 2),
+            (0, 1, 2, 3), (0, 1, 2, 3)]
+    segments = []
+    for j, sids in enumerate(plan):
+        seg = frames[j * 2 * 4:(j + 1) * 2 * 4]
+        segments.append([f for f in seg if f.stream_id in sids])
+    log = SpanLog()
+    monkeypatch.setattr(pipeline, "span", log)
+    got_eng = det_engine(oracle, fused_tick=fused)
+    got = serve_segments(ServingRuntime(got_eng), segments)
+    seeds = log.args("serve.track.seed")
+    exports = log.args("serve.track.export")
+    ref_eng = det_engine(oracle, fused_tick=fused)
+    ref = serve_segments(rows_every_boundary(ServingRuntime(ref_eng)),
+                         segments)
+    assert_segments_identical(got, ref)
+    assert_rows_identical(got_eng._exported_tracks,
+                          ref_eng._exported_tracks)
+    resident = [0, 1, 0, 1, 0, 0, 1]
+    assert [r["track_table_resident"] for r in got[0]] == resident
+    assert [a["resident"] for a in seeds] == resident
+    assert all(a["h2d_bytes"] == 0 for a in seeds if a["resident"])
+    assert all(a["h2d_bytes"] > 0 for a in seeds[1:] if not a["resident"])
+    # every segment hands its table on without a pull; only the three
+    # rebuilds that follow a device table read its rows
+    assert [a["d2h_bytes"] > 0 for a in exports].count(False) == len(plan)
+    assert [a["d2h_bytes"] > 0 for a in exports].count(True) == 3
+
+
+def test_peek_leaves_resident_table_to_the_boundary():
+    """``report()`` mid-segment (the non-destructive peek) under the
+    donating fused tick neither takes nor replaces the device table:
+    the boundaries still start from it, and everything (final rows
+    included) matches the run without peeks."""
+    frames, oracle = nvr_setup(n_streams=3, n_frames=12, rate=1.0)
+    segments = segments_of(frames, 3, 2)
+    peek_eng = det_engine(oracle, fused_tick=True)
+    plain_eng = det_engine(oracle, fused_tick=True)
+    peeked = serve_segments(ServingRuntime(peek_eng), segments, peek=True)
+    plain = serve_segments(ServingRuntime(plain_eng), segments)
+    assert_segments_identical(peeked, plain)
+    assert [r["track_table_resident"] for r in peeked[0]] == \
+        [0] + [1] * (len(segments) - 1)
+    ref_eng = det_engine(oracle, fused_tick=True)
+    serve_segments(rows_every_boundary(ServingRuntime(ref_eng)), segments)
+    assert_rows_identical(peek_eng._exported_tracks,
+                          ref_eng._exported_tracks)
+
+
 # ------------------------------------------------- contract violations
 def test_watermark_violation_raises():
     frames, oracle = nvr_setup()
