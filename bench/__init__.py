@@ -3,8 +3,10 @@
 ``python bench/run.py --workload <cell> --seed <n> --seconds <s>
 --trace <0|1>`` runs one cell of ``BENCHMARK.json``; see ``run.py``.
 Everything the yardstick needs lives here: the traffic generator
-(``traffic.py``), the plain reference (``reference.py``), the trace
-reduction (``trace_reduce.py``), the FLOP count (``flops.py``), the
-peaks (``peaks.json``), the layer name table (``layers.json``), one
-file per configuration, traffic mix and per-layer metric.
+(``generator.py``), the shared reference and the tracker's
+(``reference.py``), the comparison that decides ``correct``
+(``compare.py``), the trace reduction (``trace_reduce.py``), the peaks
+(``peaks.json``), the layer name table (``layers.json``), and one file
+per configuration (with its limits), detector family (its weights,
+reference and operation counts), traffic mix and per-layer metric.
 """

@@ -1,17 +1,25 @@
 """The numbers that decide ``correct``: what the timed path produced,
 against the plain reference (``reference.py``).
 
-Detector, on a seeded sample of the frames the window detected:
+Detector, on a seeded sample of the frames the window detected, against
+each frame's ``Candidates`` and the ``Rows`` its family's ``survivors``
+serves (``bench/families/__init__.py``).  A detection is keyed on its
+anchor where the family serves one detection per anchor, and on its
+anchor and class where it serves one per anchor and class:
 
 * ``det_gap`` -- each served detection is paired with the reference
-  candidate (any anchor) nearest to it in box and score; the largest
-  such distance (max of the box coordinates' and the score's absolute
-  differences, image units).
-* ``cls_gap`` -- at that anchor, how far the reference's logit of the
-  served class lies below the reference's best logit; the largest.
-* ``nms_miss`` -- the reference's NMS survivors and the served
-  detections' anchors, as sets per frame: the size of their symmetric
-  difference over the reference's survivor count.
+  candidate (any anchor) nearest to it in box and score, the score of
+  its class where scores are per class; the largest such distance (max
+  of the box coordinates' and the score's absolute differences, image
+  units).
+* ``cls_gap`` -- at that anchor, how far the reference's class score
+  of the served class lies below the reference's best; the largest.  A
+  family that serves any class has no such score and reads 0: its
+  classes are held by the keys.
+* ``nms_miss`` -- the keys of the reference's survivors and of the
+  served detections, as sets per frame: the size of their symmetric
+  difference, plus the served detections of a class the detector does
+  not have, over the reference's survivor count.
 
 Tracker, on a seeded sample of the cameras, over every frame the window
 served for them (detected frames step the reference tracker with the
@@ -30,7 +38,7 @@ served detections, interpolated frames coast it):
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -40,27 +48,38 @@ DET_NUMBERS = ("det_gap", "cls_gap", "nms_miss")
 TRACK_NUMBERS = ("track_miss", "track_gap")
 
 
-def detector_numbers(served: Sequence[tuple], cands: Sequence[tuple],
-                     serve: dict) -> Dict[str, float]:
+def detector_numbers(served: Sequence[tuple], cands: Sequence,
+                     serve: dict, survivors: Callable) -> Dict[str, float]:
     """``served``: per frame ``(boxes, scores, classes, valid)`` as the
-    path emitted them; ``cands``: per frame the reference's ``(boxes,
-    scores, classes, logits)`` over all anchors."""
+    path emitted them; ``cands``: per frame the reference's
+    ``Candidates``; ``survivors``: the family's suppression rule,
+    ``(cand, serve) -> Rows``."""
     det_gap = cls_gap = 0.0
     miss = total = 0
-    for (bx, sc, cl, va), (rb, rs, rc, lg) in zip(served, cands):
-        keep = ref.nms(rb, rs, score_thr=serve["score_thr"],
-                       iou_thr=serve["iou_thr"], max_out=serve["max_out"])
+    for (bx, sc, cl, va), cand in zip(served, cands):
+        per_class = np.ndim(cand.scores) == 2
+        rows = survivors(cand, serve)
+        want = set(zip(rows.anchor.tolist(), rows.cls.tolist())
+                   if per_class else rows.anchor.tolist())
+        n_cls = np.shape(cand.scores if per_class else
+                         cand.class_scores)[-1]
         picked = set()
         for j in np.flatnonzero(np.asarray(va, bool)):
-            d = np.maximum(np.abs(rb - np.asarray(bx[j], np.float64)).max(-1),
-                           np.abs(rs - float(sc[j])))
+            c = int(cl[j])
+            if not 0 <= c < n_cls:
+                miss += 1       # a class the detector does not have
+                continue
+            rs = cand.scores[:, c] if per_class else cand.scores
+            d = np.maximum(np.abs(cand.boxes - np.asarray(bx[j], np.float64))
+                           .max(-1), np.abs(rs - float(sc[j])))
             a = int(np.argmin(d))
-            picked.add(a)
+            picked.add((a, c) if per_class else a)
             det_gap = max(det_gap, float(d[a]))
-            lg_a = np.asarray(lg[a], np.float64)
-            cls_gap = max(cls_gap, float(lg_a.max() - lg_a[int(cl[j])]))
-        miss += len(picked.symmetric_difference(keep.tolist()))
-        total += len(keep)
+            if cand.class_scores is not None:
+                lg_a = np.asarray(cand.class_scores[a], np.float64)
+                cls_gap = max(cls_gap, float(lg_a.max() - lg_a[c]))
+        miss += len(picked.symmetric_difference(want))
+        total += len(want)
     return {"det_gap": det_gap, "cls_gap": cls_gap,
             "nms_miss": miss / max(total, 1)}
 

@@ -1,6 +1,6 @@
-"""Whole step: analytic conv FLOPs per frame (``bench/flops.py``) times
-the detected frame rate, over the chips' bf16 peak (``bench/peaks.json``),
-in %.  The convs run in float32 at ``Precision.HIGHEST``, so this reads
+"""Whole step: analytic FLOPs per frame (the family's
+``flops_per_frame``) times the detected frame rate, over the chips' bf16
+peak (``bench/peaks.json``), in %.  The convs run in float32 at ``Precision.HIGHEST``, so this reads
 low by design.  A device missing from the peak table is an error."""
 
 

@@ -1,160 +1,38 @@
-"""Plain reference of what the timed path computes, and the weights.
+"""Plain reference of what every detector family shares, and of the
+tracker.
 
-Nothing here imports the program.  ``make_params`` builds the detector
-weights from the seed on the device, in the layout the program's
-detector takes (``{"backbone": [{"w", "b"}, ...], "head8", "head16"}``),
-and the benchmark hands the same weights to both sides by calling it
-twice.  The reference then recomputes, from the frames the window
-served:
+Nothing here imports the program.  Each family module
+(``bench/families/``) builds its detector's weights from the seed and
+recomputes its candidates; it draws its key from ``params_key``, and a
+family that suppresses class-agnostically takes ``nms``:
 
-* the detector: stride-2 3x3 conv blocks with ReLU, two 3x3 heads on
-  the last two feature maps, two anchor kinds per cell, box decode,
-  sigmoid objectness, class argmax, then class-agnostic greedy NMS over
-  the score-sorted thresholded candidates (``iou >= iou_thr``
-  suppresses, at most ``max_out`` survivors, zero scores never kept);
+* ``nms``: greedy NMS over the score-sorted thresholded candidates
+  (``iou >= iou_thr`` suppresses, at most ``max_out`` survivors, zero
+  scores never kept);
 * the tracker, per camera: constant-velocity Kalman predict, greedy
   class-gated IoU association (globally best pair first, row-major
   ties), measurement update, coast bookkeeping, births into free slots
   in rank order with lowest-score coasting tracks evicted on overflow,
   and the confirmed-track output (w and h floored at 1).
 
-The detector reference runs in float32 with ``Precision.HIGHEST`` (what
-the configurations state); the tracker reference in float64 numpy.
-``precision="high"`` and ``dtype=bfloat16`` give the controls, one
-precision step below.
+The tracker reference runs in float64 numpy; ``dtype=bfloat16`` gives
+the control, one precision step below.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
-from typing import Dict, List, Tuple
+from typing import Dict
 
 import numpy as np
 
-N_ANCHOR_KINDS = 2
-ASPECTS = (1.0, 2.0)
 
-
-# ----------------------------------------------------------------- weights
+# ----------------------------------------------------------------- shared
 def params_key(seed: int):
     """A JAX key from any whole-number seed (also above 2**31)."""
     import jax
     words = np.random.SeedSequence(seed).generate_state(2)
     return jax.random.fold_in(jax.random.PRNGKey(int(words[0] >> 1)),
                               int(words[1] >> 1))
-
-
-def _shapes(ssd: dict) -> List[Tuple[str, int, int, int]]:
-    """(name, k, c_in, c_out) of every conv, in parameter order."""
-    out, c_in = [], 3
-    for i, c in enumerate(ssd["channels"]):
-        out.append((f"backbone.{i}", 3, c_in, c))
-        c_in = c
-    head = N_ANCHOR_KINDS * (4 + 1 + ssd["n_classes"])
-    out.append(("head8", 3, ssd["channels"][-2], head))
-    out.append(("head16", 3, ssd["channels"][-1], head))
-    return out
-
-
-def make_params(ssd: dict, seed: int):
-    """Detector weights from the seed, made on the device in one jitted
-    call: He-scaled truncated normals, zero biases, float32."""
-    import jax
-    import jax.numpy as jnp
-    shapes = tuple(_shapes(ssd))
-
-    @functools.partial(jax.jit, static_argnums=0)
-    def build(shapes, key):
-        keys = jax.random.split(key, len(shapes))
-        p = {"backbone": []}
-        for (name, k, ci, co), kk in zip(shapes, keys):
-            w = jax.random.truncated_normal(kk, -2.0, 2.0, (k, k, ci, co),
-                                            jnp.float32) / np.sqrt(k * k * ci)
-            leaf = {"w": w, "b": jnp.zeros((co,), jnp.float32)}
-            if name.startswith("backbone"):
-                p["backbone"].append(leaf)
-            else:
-                p[name] = leaf
-        return p
-
-    return build(shapes, params_key(seed))
-
-
-# ---------------------------------------------------------------- detector
-def anchors(ssd: dict) -> np.ndarray:
-    """(A, 4) xyxy anchors in [0, 1] image units: per feature map, per
-    aspect ratio, its cells in row-major order."""
-    out = []
-    for stride, scale in zip(ssd["feature_strides"], ssd["anchor_scales"]):
-        g = ssd["image_size"] // stride
-        cs = (np.arange(g) + 0.5) / g
-        cx, cy = np.meshgrid(cs, cs)
-        for ar in ASPECTS:
-            w, h = scale * np.sqrt(ar), scale / np.sqrt(ar)
-            out.append(np.stack([cx - w / 2, cy - h / 2,
-                                 cx + w / 2, cy + h / 2], -1).reshape(-1, 4))
-    return np.concatenate(out, 0).astype(np.float32)
-
-
-def forward_fn(ssd: dict, precision: str = "highest"):
-    """Jitted ``(params, images) -> (deltas, obj, cls_logits)``; the head
-    output of cell ``c`` and anchor kind ``k`` lands at row ``2c + k``.
-    ``precision`` is ``"highest"`` (float32) or ``"high"`` (the control:
-    three bfloat16 passes)."""
-    import jax
-    import jax.numpy as jnp
-    n_cls = ssd["n_classes"]
-
-    def conv1(x, w, stride, prec, out=None):
-        return jax.lax.conv_general_dilated(
-            x, w, (stride, stride), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=prec,
-            preferred_element_type=out)
-
-    def conv(leaf, x, stride):
-        if precision == "highest":
-            y = conv1(x, leaf["w"], stride, jax.lax.Precision.HIGHEST)
-        else:
-            # three bfloat16 passes (hi*hi + hi*lo + lo*hi) with float32
-            # sums: what Precision.HIGH does on a TPU, spelled out so
-            # that every backend computes it
-            bf = jnp.bfloat16
-            xh = x.astype(bf)
-            wh = leaf["w"].astype(bf)
-            xl = (x - xh.astype(x.dtype)).astype(bf)
-            wl = (leaf["w"] - wh.astype(x.dtype)).astype(bf)
-            y = sum(conv1(a, b, stride, jax.lax.Precision.DEFAULT,
-                          jnp.float32)
-                    for a, b in ((xh, wh), (xh, wl), (xl, wh)))
-        return y + leaf["b"]
-
-    def fwd(params, images):
-        x, feats = images, []
-        for leaf in params["backbone"]:
-            x = jnp.maximum(conv(leaf, x, 2), 0.0)
-            feats.append(x)
-        outs = []
-        for f, name in ((feats[-2], "head8"), (feats[-1], "head16")):
-            y = conv(params[name], f, 1)
-            b, g = y.shape[0], y.shape[1]
-            outs.append(y.reshape(b, g * g * N_ANCHOR_KINDS, 5 + n_cls))
-        y = jnp.concatenate(outs, 1)
-        return y[..., :4], y[..., 4], y[..., 5:]
-
-    return jax.jit(fwd)
-
-
-def decode(deltas, obj, logits, anc):
-    """Candidates of one frame: boxes (A, 4), scores (A,), classes (A,)."""
-    deltas = np.asarray(deltas, np.float64)
-    anc = np.asarray(anc, np.float64)
-    wh0 = anc[:, 2:] - anc[:, :2]
-    c0 = (anc[:, :2] + anc[:, 2:]) / 2
-    c = c0 + deltas[:, :2] * wh0
-    wh = wh0 * np.exp(np.clip(deltas[:, 2:], -4, 4))
-    boxes = np.concatenate([c - wh / 2, c + wh / 2], -1)
-    scores = 1.0 / (1.0 + np.exp(-np.asarray(obj, np.float64)))
-    return boxes, scores, np.argmax(np.asarray(logits), -1)
 
 
 def iou_one(box, boxes) -> np.ndarray:

@@ -15,12 +15,13 @@ standard error.
 
 A run:
 
-1. makes the detector weights from ``--seed`` on the device
-   (``reference.make_params``) and renders the mix's frame pool;
+1. makes the detector weights from ``--seed`` on the device (the
+   family's ``make_params``) and renders the mix's frame pool;
 2. builds the cell's engine -- ``DetectionEngine`` on one chip,
-   ``ShardedDetectionEngine`` over a 4-chip serving mesh -- with the
-   configuration's serving keywords and every other option at the
-   program's default, and drives it through ``ServingRuntime``;
+   ``ShardedDetectionEngine`` over a 4-chip serving mesh -- on the
+   family's ``program_config``, with the configuration's serving
+   keywords and every other option at the program's default, and
+   drives it through ``ServingRuntime``;
 3. warms up: the detect program at micro-batch buckets 1/2/4/8, then
    about a second of the cell's own traffic (tracker tick at the cell's
    camera count);
@@ -32,12 +33,31 @@ A run:
    ``failed``;
 5. after the window, ``drain()``; then the peak device memory is read,
    the engine is freed and the plain reference checks a seeded sample
-   of what the window served (``compare.py``).
+   of what the window served (``compare.py``) against the limits in the
+   configuration's file.
+
+Nothing here depends on the detector's architecture.  A configuration
+file names its family, and ``bench/families/<family>.py`` gives the
+weights, the program's configuration, the plain reference and its
+suppression rule, and the operation and byte counts
+(``bench/families/__init__.py``); they moved there from this file,
+``reference.py`` and ``flops.py`` with their arithmetic unchanged.  The
+limits of ``correct`` sit in the configuration's own file, under
+``limits``, with the values the shared ``limits.json`` held.  What the harness asks
+of the program: ``DetectionEngine(cfg=...)`` builds the detector from
+the family's ``program_config``, its detect program is ``_infer(images)``
+compiled as ``jit_infer``, and the scopes that program names are what
+``Summary.scopes`` counts.
 
 ``setup_s`` runs from the start of this script to the window's opening.
 With ``--trace 1`` the last seconds of the window are traced by the JAX
 profiler, and the per-layer metrics are read by the files in
-``bench/metrics/`` (one per metric, found by name).
+``bench/metrics/`` (one per metric, found by name).  Once the window has
+closed, the detect program's compiled text is read at every micro-batch
+bucket (from the in-memory cache: nothing compiles) to name the scopes
+of its device ops (``trace_reduce.parse_hlo``).  A reader may leave a
+note in ``ctx["notes"]`` (which bound binds a roofline); the result
+line carries them under ``notes``.
 
 ``--control`` puts the lower-precision control (the reference one
 precision step down) in the program's place: ``checks`` and ``correct``
@@ -73,7 +93,6 @@ for _p in (ROOT / "src", ROOT):
 
 from bench import compare, generator, reference  # noqa: E402
 from bench import trace_reduce  # noqa: E402
-from bench.flops import conv_flops_per_frame  # noqa: E402
 from bench.stats import percentile  # noqa: E402
 
 WARM_SECONDS = 1.0
@@ -101,13 +120,15 @@ class Cell:
         self.name = name
         conf = {c["name"]: c for c in self.bench["configs"]}[self.w["config"]]
         self.config = load_json(ROOT / conf["file"])
+        self.family = load_module("families", self.config["family"])
+        self.family.check(self.config)
         d = load_json(BENCH / "traffic" / f"{self.w['traffic']}.json")
         if cameras is not None:
             d["cameras"] = cameras
         self.mix = generator.Mix.from_dict(self.w["traffic"], d)
         self.chips = int(self.w["chips"])
-        self.ssd = self.config["ssd"]
         self.serve = self.config["serving"]
+        self.limits = self.config["limits"]
 
     def metrics(self, kind: str):
         return [m for m in self.bench[kind]
@@ -138,15 +159,9 @@ def device_check(jax, chips: int):
 
 
 def build_engine(cell: Cell, params):
-    from repro.detector import SSDConfig
     from repro.serving import DetectionEngine, ShardedDetectionEngine
-    ssd = cell.ssd
-    cfg = SSDConfig(image_size=ssd["image_size"], n_classes=ssd["n_classes"],
-                    channels=tuple(ssd["channels"]),
-                    anchor_scales=tuple(ssd["anchor_scales"]),
-                    feature_strides=tuple(ssd["feature_strides"]))
-    kw = dict(cfg=cfg, params=params, track_and_interpolate=True,
-              **cell.serve)
+    kw = dict(cfg=cell.family.program_config(cell.config), params=params,
+              track_and_interpolate=True, **cell.serve)
     if cell.chips == 1:
         return DetectionEngine(**kw)
     from repro.launch.mesh import make_serving_mesh
@@ -367,35 +382,21 @@ def reference_check(cell: Cell, seed: int, responses, finals, fr: Frames,
     count of detections the reference tracker associated; with
     ``control`` also the numbers of the lower-precision control, its
     outputs in the program's place."""
-    import jax
     rng = np.random.default_rng(np.random.SeedSequence([seed, 12]))
-    ssd, serve = cell.ssd, cell.serve
+    fam, cfg, serve = cell.family, cell.config, cell.serve
     n_win = len(fr.reqs)
     served = [r for r in responses if r.rid < n_win]
     fresh = [r for r in served if not r.interpolated]
     pick = sorted(rng.choice(len(fresh), min(DET_SAMPLE, len(fresh)),
                              replace=False)) if fresh else []
     frames = [fresh[i] for i in pick]
-    params = reference.make_params(ssd, seed)
-    anc = reference.anchors(ssd)
-
-    def candidates(precision):
-        fwd = reference.forward_fn(ssd, precision)
-        out = []
-        for a in range(0, len(frames), 16):
-            blk = frames[a:a + 16]
-            x = np.stack([pool[fr.idx[r.rid]] for r in blk])
-            with jax.default_matmul_precision("highest"):
-                dl, ob, lg = (np.asarray(v) for v in fwd(params, x))
-            for f in range(len(blk)):
-                rb, rs, rc = reference.decode(dl[f], ob[f], lg[f], anc)
-                out.append((rb, rs, rc, lg[f]))
-        return out
-
-    cands = candidates("highest")
+    params = fam.make_params(cfg, seed)
+    images = np.stack([pool[fr.idx[r.rid]] for r in frames]) if frames \
+        else pool[:0]
+    cands = fam.candidates(cfg, params, images, "highest")
     nums = compare.detector_numbers(
         [(r.boxes, r.scores, r.classes, r.valid) for r in frames], cands,
-        serve)
+        serve, fam.survivors)
     cams = sorted(rng.choice(cell.mix.cameras,
                              min(TRACK_SAMPLE, cell.mix.cameras),
                              replace=False).tolist())
@@ -408,36 +409,48 @@ def reference_check(cell: Cell, seed: int, responses, finals, fr: Frames,
     if not control:
         return nums, None, matched
     ctl_served = []
-    for rb, rs, rc, _ in candidates("high"):
-        keep = reference.nms(rb, rs, score_thr=serve["score_thr"],
-                             iou_thr=serve["iou_thr"],
-                             max_out=serve["max_out"])
-        m = serve["max_out"]
+    m = serve["max_out"]
+    for cand in fam.candidates(cfg, params, images, "high"):
+        rows = fam.survivors(cand, serve)
+        k = len(rows.anchor)
         bx = np.zeros((m, 4))
         sc = np.zeros(m)
         cl = np.zeros(m, np.int64)
         va = np.zeros(m, bool)
-        bx[:len(keep)], sc[:len(keep)] = rb[keep], rs[keep]
-        cl[:len(keep)], va[:len(keep)] = rc[keep], True
+        bx[:k], sc[:k] = rows.boxes, rows.scores
+        cl[:k], va[:k] = rows.cls, True
         ctl_served.append((bx, sc, cl, va))
-    ctl = compare.detector_numbers(ctl_served, cands, serve)
+    ctl = compare.detector_numbers(ctl_served, cands, serve, fam.survivors)
     import ml_dtypes
     ctl.update(compare.tracker_numbers(
         *compare.replay_tracker(streams, prm, ml_dtypes.bfloat16), prm)[0])
     return nums, ctl, matched
 
 
-def load_limits(config: str) -> dict:
-    return load_json(BENCH / "limits.json")[config]
+def detect_hlo(eng, size: int):
+    """The detect program's compiled text at every micro-batch bucket,
+    read once the window has closed: the buckets were compiled in
+    set-up, so this only looks them up.  The mesh path's program is not
+    reachable from here (no texts: no scopes)."""
+    if not hasattr(eng, "_infer"):
+        return []
+    return [eng._infer.lower(np.zeros((b, size, size, 3), np.float32))
+            .compile().as_text() for b in BUCKETS]
+
+
+def load_module(kind: str, name: str):
+    """``bench/<kind>/<name>.py``, loaded by its path: a family or a
+    metric reader that a later change adds needs no edit here."""
+    path = BENCH / kind / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def read_metric(name: str, ctx: dict):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return load_module("metrics", name).read(ctx)
 
 
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
@@ -447,14 +460,15 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     cell = Cell(name, cameras)
     if check_device:
         device_check(jax, cell.chips)
-    mix = cell.mix
+    mix, fam = cell.mix, cell.family
+    size = fam.image_size(cell.config)
     dev = jax.devices()[0]
-    params = reference.make_params(cell.ssd, seed)
+    params = fam.make_params(cell.config, seed)
     rng = np.random.default_rng(np.random.SeedSequence([seed, 7]))
-    pool = generator.render_pool(mix, cell.ssd["image_size"])
+    pool = generator.render_pool(mix, size)
     offsets = generator.camera_offsets(rng, mix.cameras, mix.pool_frames)
     eng = build_engine(cell, params)
-    warm_buckets(eng, cell.ssd["image_size"])
+    warm_buckets(eng, size)
     warm = Frames(mix, WARM_SECONDS, pool, offsets)
     rt = runtime(eng, mix.cameras)
     drive(rt, warm, mix.emit_period_s)
@@ -476,6 +490,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     mem = peak_memory(jax, cell.chips)
     responses = rep["responses"]
     finals = final_tracks(eng)
+    hlo = detect_hlo(eng, size) if trace else []
     del rt, eng, rep
     gc.collect()
 
@@ -486,7 +501,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     span_s = max(seconds, float(d.emit_t[-1]))
     detected = int(d.detected.sum())
     ctx = {
-        "cell": cell.w, "config": cell.config, "mix": mix, "chips": cell.chips,
+        "cell": cell.w, "config": cell.config, "family": fam, "mix": mix,
+        "chips": cell.chips,
         "seconds": seconds, "span_s": span_s,
         "frames": len(fr.reqs), "detected": detected,
         "emitted": int(d.emitted.sum()),
@@ -495,18 +511,19 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         "lateness_ms": (d.ingest_t - fr.due) * 1e3,
         "ingest_advance_s": d.ingest_advance_s,
         "boundary_s": d.boundary_s,
-        "flops_per_frame": conv_flops_per_frame(cell.ssd),
+        "flops_per_frame": fam.flops_per_frame(cell.config),
         "peaks": load_json(BENCH / "peaks.json").get(dev.device_kind),
         "device_kind": dev.device_kind,
         "setup_s": setup_s,
         "trace": None,
+        "notes": {},
     }
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": mem}
     result_extra = {}
     if trace:
         summ = trace_reduce.summarize(trace_reduce.load(trace_dir),
-                                      load_json(BENCH / "layers.json"))
+                                      load_json(BENCH / "layers.json"), hlo)
         shutil.rmtree(trace_dir, ignore_errors=True)
         ctx["trace"] = summ
         t_lo = d.trace_from
@@ -527,11 +544,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
 
     nums, ctl, matched = reference_check(cell, seed, responses, finals, fr,
                                          pool, control)
-    limits = load_limits(cell.w["config"])
     failed = int(d.failed.sum())
     checks = {"failed": {"value": failed, "limit": 0}}
     for k, v in (nums if ctl is None else ctl).items():
-        checks[k] = {"value": v, "limit": limits.get(k)}
+        checks[k] = {"value": v, "limit": cell.limits.get(k)}
     correct = all(c["limit"] is None or c["value"] <= c["limit"]
                   for c in checks.values()) and detected > 0
     out = {"correct": bool(correct), "attempted": len(fr.reqs),
@@ -546,10 +562,14 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         "emit_p50_first_third_ms": percentile(lat_ms[thirds[0]], 50),
         "emit_p50_last_third_ms": percentile(lat_ms[thirds[-1]], 50),
     }
+    if ctx["notes"]:
+        out["notes"] = ctx["notes"]
     if ctl is not None:
         out["program"] = nums
     out["checks"] = checks
     log(f"compiles inside the window: {compiles.n}")
+    for k, v in ctx["notes"].items():
+        log(f"note {k}: {v}")
     for k, c in checks.items():
         log(f"check {k}: {c['value']} limit {c['limit']}")
     return out

@@ -51,7 +51,7 @@ def test_control_fails():
     out = _run(control=True)
     assert not out["correct"]
     assert _failing(out), out["checks"]
-    limits = run.load_limits("minissd64")
+    limits = run.Cell(CELL).limits
     assert all(v <= limits[k] for k, v in out["program"].items()), \
         out["program"]
 

@@ -1,5 +1,6 @@
 """Every file BENCHMARK.json names loads by name, and the file keeps to
 the shape the harness relies on."""
+import ast
 import importlib.util
 import json
 import re
@@ -17,10 +18,18 @@ CONFIG_FILES = sorted((run.BENCH / "configs").glob("*.json"))
 LISTED = {c["file"]: c for c in BENCH["configs"]}
 
 
+FAMILY_FILES = sorted(p for p in (run.BENCH / "families").glob("*.py")
+                      if p.stem != "__init__")
+FAMILY_API = ("check", "image_size", "program_config", "make_params",
+              "candidates", "survivors", "flops_per_frame", "flops_by_scope",
+              "bytes_by_scope")
+
+
 @pytest.mark.parametrize("path", CONFIG_FILES, ids=lambda p: p.stem)
 def test_config_file_loads(path):
     """Every configuration file, listed in BENCHMARK.json or kept for a
-    cell to come, loads and has its limits."""
+    cell to come, loads and has its limits; its family checks its own
+    keys."""
     cfg = json.loads(path.read_text())
     assert cfg["name"] == path.stem
     conf = LISTED.get(str(path.relative_to(run.ROOT)))
@@ -28,13 +37,45 @@ def test_config_file_loads(path):
         assert cfg["name"] == conf["name"]
         assert cfg["source"] == conf["source"]
         assert sorted(cfg["reduced"]) == sorted(conf["reduced"])
-    ssd = cfg["ssd"]
-    assert len(ssd["feature_strides"]) == len(ssd["anchor_scales"]) == 2
+    assert NAME.match(cfg["family"])
     assert set(cfg["serving"]) == {"n_replicas", "max_micro_batch",
                                    "score_thr", "iou_thr", "max_out"}
-    limits = run.load_limits(cfg["name"])
-    assert set(limits) == {"det_gap", "cls_gap", "nms_miss", "track_miss",
-                           "track_gap"}
+    assert set(cfg["limits"]) == {"det_gap", "cls_gap", "nms_miss",
+                                  "track_miss", "track_gap"}
+    assert all(v > 0 for v in cfg["limits"].values())
+    run.load_module("families", cfg["family"]).check(cfg)
+
+
+def _repro_imports(node):
+    return [n for n in ast.walk(node)
+            if isinstance(n, ast.ImportFrom) and (n.module or "").startswith(
+                "repro") or isinstance(n, ast.Import) and any(
+                a.name.startswith("repro") for a in n.names)]
+
+
+@pytest.mark.parametrize("path", FAMILY_FILES, ids=lambda p: p.stem)
+def test_family_module_gives_the_harness_interface(path):
+    """A family gives every function the harness calls, and imports the
+    program in ``program_config`` alone: its reference stands apart."""
+    fam = run.load_module("families", path.stem)
+    assert all(callable(getattr(fam, f)) for f in FAMILY_API)
+    tree = ast.parse(path.read_text())
+    inside = [n for f in tree.body if isinstance(f, ast.FunctionDef)
+              and f.name == "program_config" for n in _repro_imports(f)]
+    assert _repro_imports(tree) == inside
+
+
+@pytest.mark.parametrize("change", [
+    {"feature_strides": [16, 32]},
+    {"anchor_scales": [0.1, 0.2, 0.3]},
+    {"image_size": 60},
+    {"aspects": [1.0, 2.0]},
+])
+def test_ssd_family_refuses_what_the_program_cannot_run(change):
+    cfg = run.Cell("minissd64-eth14-steady").config
+    bad = dict(cfg, ssd=dict(cfg["ssd"], **change))
+    with pytest.raises(ValueError):
+        run.load_module("families", "ssd").check(bad)
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])

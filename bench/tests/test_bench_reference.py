@@ -5,30 +5,83 @@ death."""
 import numpy as np
 import pytest
 
-from bench import compare, reference, run
+from bench import compare, generator, reference, run
 
 
 def test_detector_reference_matches_decode_detections():
     import jax
     from repro.detector import SSDConfig, decode_detections, make_anchors
     cell = run.Cell("minissd64-eth14-steady")
-    ssd, serve = cell.ssd, cell.serve
-    params = reference.make_params(ssd, 11)
-    cfg = SSDConfig()
-    np.testing.assert_array_equal(make_anchors(cfg), reference.anchors(ssd))
+    fam, serve = cell.family, cell.serve
+    params = fam.make_params(cell.config, 11)
+    cfg = fam.program_config(cell.config)
+    assert cfg == SSDConfig()
+    np.testing.assert_array_equal(make_anchors(cfg),
+                                  fam.anchors(cell.config["ssd"]))
     x = np.random.default_rng(0).random((4, 64, 64, 3)).astype(np.float32)
     got = jax.jit(lambda p, im: decode_detections(
         p, cfg, im, make_anchors(cfg), **{k: serve[k] for k in (
             "score_thr", "iou_thr", "max_out")}))(params, x)
-    dl, ob, lg = (np.asarray(v) for v in reference.forward_fn(ssd)(params, x))
-    anc = reference.anchors(ssd)
-    cands = [reference.decode(dl[f], ob[f], lg[f], anc) + (lg[f],)
-             for f in range(4)]
+    cands = fam.candidates(cell.config, params, x, "highest")
     served = [tuple(np.asarray(o)[f] for o in got) for f in range(4)]
-    nums = compare.detector_numbers(served, cands, serve)
+    nums = compare.detector_numbers(served, cands, serve, fam.survivors)
     assert nums["det_gap"] < 1e-5
     assert nums["cls_gap"] == 0.0 and nums["nms_miss"] == 0.0
     assert sum(int(np.asarray(got[3])[f].sum()) for f in range(4)) > 0
+
+
+# What the reference check read on the frames of ``_fixed_served`` before
+# the detector's reference moved into ``bench/families/ssd.py``, when
+# ``run.reference_check`` called ``reference.forward_fn``, ``decode`` and
+# ``nms`` itself: the program's numbers, then the control's.
+BEFORE_THE_MOVE = (
+    {"det_gap": 1.1329253402081463e-07, "cls_gap": 0.04046659916639328,
+     "nms_miss": 0.0009191176470588235, "track_miss": 0.0,
+     "track_gap": 7.103965872775274e-08},
+    {"det_gap": 3.113500158802296e-07, "cls_gap": 0.0, "nms_miss": 0.0,
+     "track_miss": 0.0, "track_gap": 0.0047493577003479})
+FIXED_SEED = 2**31 + 5
+
+
+def _fixed_served(cell, seed):
+    """One second of 3 cameras, every fifth frame interpolated: the
+    program's detections of each frame (one class flipped and one
+    survivor dropped, so that every number reads), the tracker replayed
+    in float32."""
+    import types
+    mix = cell.mix
+    rng = np.random.default_rng(np.random.SeedSequence([seed, 7]))
+    pool = generator.render_pool(mix, cell.family.image_size(cell.config))
+    offsets = generator.camera_offsets(rng, mix.cameras, mix.pool_frames)
+    fr = run.Frames(mix, 1.0, pool, offsets)
+    eng = run.build_engine(cell, cell.family.make_params(cell.config, seed))
+    out = [np.array(o) for o in eng._infer(np.stack([r.image
+                                                     for r in fr.reqs]))]
+    first = np.flatnonzero(out[3][0])[0]
+    out[2][0, first] = (out[2][0, first] + 1) % 3
+    out[3][1, np.flatnonzero(out[3][1])[-1]] = False
+    streams = {}
+    for j, r in enumerate(fr.reqs):
+        streams.setdefault(r.stream_id, []).append(types.SimpleNamespace(
+            rid=r.rid, interpolated=j % 5 == 3, boxes=out[0][j],
+            scores=out[1][j], classes=out[2][j], valid=out[3][j]))
+    rep, finals = compare.replay_tracker(streams, reference.TrackerParams(),
+                                         np.float32)
+    responses = sorted((types.SimpleNamespace(
+        rid=a.rid, stream_id=s, interpolated=b.interpolated, boxes=b.boxes,
+        scores=b.scores, classes=b.classes, valid=b.valid,
+        track_ids=b.track_ids)
+        for s, rs in streams.items() for a, b in zip(rs, rep[s])),
+        key=lambda r: r.rid)
+    return responses, finals, fr, pool
+
+
+def test_reference_check_reads_as_before_the_family_move():
+    cell = run.Cell("minissd64-eth14-steady", cameras=3)
+    responses, finals, fr, pool = _fixed_served(cell, FIXED_SEED)
+    nums, ctl, _ = run.reference_check(cell, FIXED_SEED, responses, finals,
+                                       fr, pool, True)
+    assert (nums, ctl) == BEFORE_THE_MOVE
 
 
 def _sequence(rng, n_frames=40, D=8):

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from bench import run, stats
-from bench.flops import conv_flops_per_frame
+
+SSD = run.load_module("families", "ssd")
 
 
 def test_percentiles_are_exact_over_all_values():
@@ -19,27 +20,48 @@ def test_percentiles_are_exact_over_all_values():
 
 
 def test_flops_of_both_configurations():
-    ssd416 = run.load_json(run.BENCH / "configs" / "ssd416-c80.json")["ssd"]
-    mini = run.Cell("minissd64-eth14-steady").ssd
+    ssd416 = run.load_json(run.BENCH / "configs" / "ssd416-c80.json")
+    mini = run.Cell("minissd64-eth14-steady").config
     # 208^2*9*3*16 + 104^2*9*16*32 + 52^2*9*32*64 + 26^2*9*64*128
     # + 13^2*9*128*256 + heads 26^2*9*128*170 + 13^2*9*256*170, times 2
-    assert conv_flops_per_frame(ssd416) == 833264640
-    assert conv_flops_per_frame(mini) == 8257536
+    assert SSD.flops_per_frame(ssd416) == 833264640
+    assert SSD.flops_per_frame(mini) == 8257536
+
+
+def _xla_cost(cfg):
+    import jax
+    params = SSD.make_params(cfg, 0)
+    size = SSD.image_size(cfg)
+    x = np.zeros((1, size, size, 3), np.float32)
+    cost = SSD.forward_fn(cfg["ssd"]).lower(params, x).compile() \
+        .cost_analysis()
+    return params, cost[0] if isinstance(cost, list) else cost
 
 
 def test_flops_agree_with_xla_cost_analysis():
-    import jax
-    from bench import reference
-    ssd = run.Cell("minissd64-eth14-steady").ssd
-    params = reference.make_params(ssd, 0)
-    x = np.zeros((1, 64, 64, 3), np.float32)
-    cost = reference.forward_fn(ssd).lower(params, x).compile() \
-        .cost_analysis()
-    cost = cost[0] if isinstance(cost, list) else cost
+    cfg = run.Cell("minissd64-eth14-steady").config
     # XLA leaves out the taps that fall on SAME padding; the analytic
     # count keeps them, as a dense convolution computes them
-    xla = cost["flops"]
-    assert 0.85 * conv_flops_per_frame(ssd) < xla < conv_flops_per_frame(ssd)
+    xla = _xla_cost(cfg)[1]["flops"]
+    assert 0.85 * SSD.flops_per_frame(cfg) < xla < SSD.flops_per_frame(cfg)
+
+
+@pytest.mark.parametrize("name", ["minissd64", "ssd416-c80"])
+def test_backbone_counts_are_lower_bounds(name):
+    """The scope's operations leave out the padding taps and the bias
+    adds and ReLUs that XLA counts besides, so they lie just under its
+    cost analysis; its bytes are the frame and every weight, once."""
+    import jax
+    cfg = run.load_json(run.BENCH / "configs" / f"{name}.json")
+    params, cost = _xla_cost(cfg)
+    flops = SSD.flops_by_scope(cfg)["backbone"]
+    assert 0.97 * cost["flops"] < flops <= cost["flops"]
+    assert flops < SSD.flops_per_frame(cfg)
+    n_weights = sum(leaf.size for leaf in jax.tree_util.tree_leaves(params))
+    size = SSD.image_size(cfg)
+    for fpc in (1, 2.5, 8):
+        assert SSD.bytes_by_scope(cfg, fpc)["backbone"] == \
+            4 * (fpc * size * size * 3 + n_weights)
 
 
 def _resp(rid, interp=False):
