@@ -178,10 +178,10 @@ def served_trace(cfg, params, clog):
 def reference_check(eng, trace, cfg, n_batches: int = 3):
     import jax
     import numpy as np
-    from repro.detector import decode_detections
+    from repro.detector import decode_detections, make_anchors
     cpu = jax.devices("cpu")[0]
     params = jax.device_put(eng.params, cpu)
-    anchors = jax.device_put(eng.anchors, cpu)
+    anchors = jax.device_put(make_anchors(cfg), cpu)
     ref_fn = jax.jit(lambda p, a, x: decode_detections(
         p, cfg, x, a, score_thr=SCORE_THR, iou_thr=IOU_THR,
         max_out=MAX_OUT, use_pallas=False))

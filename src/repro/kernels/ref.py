@@ -82,6 +82,49 @@ def batched_nms_ref(boxes, scores, iou_thr: float = 0.5,
         lambda b, s: nms_ref(b, s, iou_thr, max_out))(boxes, scores)
 
 
+def class_nms_ref(boxes, scores, *, score_thr: float, iou_thr: float,
+                  max_out: int):
+    """Class-aware greedy-NMS oracle (numpy loops) for
+    ``class_nms.class_nms``: per frame and per class, greedy NMS over the
+    pairs scored at least ``score_thr`` in descending score (lower anchor
+    first on ties), suppressing IoU >= ``iou_thr``; the survivors of all
+    classes merged in descending score, ties to the lower flat index
+    ``a * C + c``, the first ``max_out`` served.
+
+    boxes (B, A, 4), scores (B, C, A) -> (anchor, cls, score, valid),
+    each (B, max_out), rows past the survivors zero and not valid."""
+    import numpy as np
+    boxes = np.asarray(boxes, np.float32)
+    scores = np.asarray(scores, np.float32)
+    B, C, A = scores.shape
+    out = [np.zeros((B, max_out), np.int32), np.zeros((B, max_out), np.int32),
+           np.zeros((B, max_out), np.float32), np.zeros((B, max_out), bool)]
+    for b in range(B):
+        bx = boxes[b]
+        area = np.prod(bx[:, 2:] - bx[:, :2], -1)
+        found = []
+        for c in range(C):
+            s = np.where(scores[b, c] >= score_thr, scores[b, c],
+                         np.float32(0))
+            alive = s > 0
+            kept = 0
+            for a in np.argsort(-s, kind="stable"):
+                if not alive[a] or kept == max_out:
+                    continue
+                kept += 1
+                found.append((-s[a], a * C + c, a, c))
+                tl = np.maximum(bx[a, :2], bx[:, :2])
+                br = np.minimum(bx[a, 2:], bx[:, 2:])
+                inter = np.prod(np.clip(br - tl, 0, None), -1)
+                iou = inter / np.maximum(area[a] + area - inter,
+                                         np.float32(1e-9))
+                alive &= ~(iou >= iou_thr)
+        for k, (neg, _, a, c) in enumerate(sorted(found)[:max_out]):
+            out[0][b, k], out[1][b, k] = a, c
+            out[2][b, k], out[3][b, k] = -neg, True
+    return tuple(jnp.asarray(o) for o in out)
+
+
 def greedy_assign_ref(t_boxes, d_boxes, t_mask, d_mask, t_cls=None,
                       d_cls=None, iou_thr: float = 0.3):
     """Greedy IoU-association oracle for the tracking subsystem.

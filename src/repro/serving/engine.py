@@ -346,6 +346,21 @@ class ServingEngine:
         }
 
 
+class BoundProgram:
+    """A jitted ``fn(params, images)`` with the weights bound: callable
+    and lowerable with the images alone, so the weights stay arguments
+    of the compiled program and never become its constants."""
+
+    def __init__(self, jitted, params):
+        self.jitted, self.params = jitted, params
+
+    def __call__(self, images):
+        return self.jitted(self.params, images)
+
+    def lower(self, images):
+        return self.jitted.lower(self.params, images)
+
+
 class DetectionEngine:
     """Video-frame payload path: the paper's "n detection models" served
     from the same scheduler/replica machinery as the token path, with
@@ -442,22 +457,24 @@ class DetectionEngine:
         if track_and_interpolate:
             from ..tracking import TrackerConfig   # lazy: avoids cycles
             self.tracker_cfg = tracker_cfg or TrackerConfig()
+        self._counter = None
         if detect_fn is None:
-            from ..detector import SSDConfig, decode_detections, \
-                init_ssd, make_anchors
+            from ..detector import SSDConfig, detector_for
             self.cfg = cfg or SSDConfig()
-            self.params = params if params is not None else init_ssd(
+            det = detector_for(self.cfg, score_thr=score_thr,
+                               iou_thr=iou_thr, max_out=max_out,
+                               use_pallas=use_pallas)
+            self.params = params if params is not None else det.init(
                 self.cfg, jax.random.PRNGKey(seed))
-            self.anchors = jnp.asarray(make_anchors(self.cfg))
+            self._counter = det.counter
 
-            def infer(imgs):
-                return decode_detections(
-                    self.params, self.cfg, imgs, self.anchors,
-                    score_thr=score_thr, iou_thr=iou_thr, max_out=max_out,
-                    use_pallas=use_pallas)
+            def infer(params, imgs):
+                return det.infer(params, imgs)
 
-            # a named function: its XLA module is ``jit_infer``
-            self._infer = jax.jit(infer)
+            # a named function: its XLA module is ``jit_infer``; the
+            # weights are its arguments, so no seed's weights are
+            # constants of the program
+            self._infer = BoundProgram(jax.jit(infer), self.params)
         else:
             self.cfg = cfg
         speeds = list(replica_speeds or [1.0] * n_replicas)
@@ -536,7 +553,7 @@ class DetectionEngine:
         frames = (len(images) if rids is None
                   else sum(r >= 0 for r in rids))
         with span("serve.detect", frames=frames,
-                  padded=len(images) - frames):
+                  padded=len(images) - frames) as det:
             if self._detect_fn is not None:
                 kw = {}
                 if model is not None and self._fn_takes_model:
@@ -551,8 +568,18 @@ class DetectionEngine:
                 with span("serve.detect.run"):
                     out = jax.block_until_ready(self._infer(x))
                 with span("serve.detect.pull") as sp:
-                    out = tuple(np.asarray(o) for o in out)
+                    if self._counter:
+                        # one pull for the detections and the counter
+                        out = tuple(jax.device_get(out))
+                    else:
+                        out = tuple(np.asarray(o) for o in out)
                     sp.set_metadata(d2h_bytes=sum(o.nbytes for o in out))
+                if self._counter:
+                    real = (np.arange(len(images)) < frames if rids is None
+                            else np.asarray(rids) >= 0)
+                    det.set_metadata(
+                        **{self._counter: int(out[4][real].sum())})
+                    out = out[:4]
         return out, time.perf_counter() - t0
 
     def _model_caps(self) -> Dict[str, float]:

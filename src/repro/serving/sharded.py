@@ -49,6 +49,16 @@ from .engine import DetectionEngine, FrameRequest
 from .models import cascade_report_keys
 
 
+def _require_ssd(cfg):
+    """The mesh paths build only the mini-SSD: refuse any other detector
+    rather than serve an SSD in its place."""
+    from ..detector import SSDConfig
+    if cfg is not None and not isinstance(cfg, SSDConfig):
+        raise ValueError(f"the sharded detection paths serve only an "
+                         f"SSDConfig detector, not a {type(cfg).__name__}: "
+                         "use DetectionEngine on one device")
+
+
 def make_spmd_detect(cfg, params, mesh, *, score_thr: float = 0.4,
                      iou_thr: float = 0.5, max_out: int = 32,
                      use_pallas: bool = False):
@@ -67,6 +77,7 @@ def make_spmd_detect(cfg, params, mesh, *, score_thr: float = 0.4,
     (blocking, so the engine's wall-time measurement brackets real
     device work)."""
     from ..detector import decode_detections, make_anchors
+    _require_ssd(cfg)
     anchors = jnp.asarray(make_anchors(cfg))
 
     def infer(imgs):
@@ -498,6 +509,8 @@ class ShardedDetectionEngine:
                 "are mutually exclusive: an arbitrary Python callable "
                 "cannot be compiled across mesh shards — drop mesh= to "
                 "use the scheduler fallback path")
+        if detect_fn is None:
+            _require_ssd(cfg)
         self.n_shards = n_shards
         self.mesh = mesh
         self._shared_detect = None

@@ -1,7 +1,8 @@
 """Ahead-of-time compiles of the frame path for a TPU v5e, with no chip
 attached: the four Pallas kernels (``interpret=False``, so Mosaic lowers
-them), the jitted ``decode_detections`` program and the fused tracker
-tick, at the shapes the served path uses.  The TPU compiler refuses
+them), the jitted ``decode_detections`` program, the YOLOv3 detect
+program at its published widths and the fused tracker tick, at the
+shapes the served path uses.  The TPU compiler refuses
 here what it would refuse on the chip (block tiling, unsupported
 primitives, VMEM), so these tests catch it without chip time.
 
@@ -106,6 +107,24 @@ def test_decode_detections_compiles_for_v5e(one_chip, mosaic, use_pallas):
         p, cfg, x, a, max_out=MAX_OUT, use_pallas=use_pallas)).lower(
         params, anchors, images).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == use_pallas
+
+
+def test_yolov3_detect_program_compiles_for_v5e(one_chip):
+    """The served YOLOv3-416 program at micro-batch 8 with abstract
+    weights: possible because the weights are arguments of the program
+    (about a minute of compile on a CPU host)."""
+    from repro.detector import YOLOv3Config, detector_for
+    cfg = YOLOv3Config()
+    det = detector_for(cfg, score_thr=0.5, iou_thr=0.45, max_out=MAX_OUT)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: det.init(cfg, jax.random.PRNGKey(0))))
+    images = _spec(one_chip, (B, cfg.image_size, cfg.image_size, 3))
+    compiled = jax.jit(det.infer).lower(params, images).compile()
+    text = compiled.as_text()
+    assert "convolution" in text and "tpu_custom_call" not in text
+    out = compiled.memory_analysis()
+    # the weights come in as arguments, 248 MB of float32
+    assert out.argument_size_in_bytes >= 4 * 62_001_757
 
 
 @pytest.mark.parametrize("use_pallas", [False, True],
