@@ -192,9 +192,8 @@ def proxy_detect_fn(video: SyntheticVideo, detector: ProxyDetector,
     ``detect_fn`` interface: an ``(images, rids) -> (boxes, scores,
     classes, valid)`` callable that looks detections up by frame id
     (rid) instead of running the mini-SSD — the oracle detector the
-    engine tests and ``benchmarks/tracking_bench.py`` share.  The
-    single-stream special case of ``proxy_detect_fn_streams`` (rid ==
-    frame index, one camera)."""
+    engine tests share.  The single-stream special case of
+    ``proxy_detect_fn_streams`` (rid == frame index, one camera)."""
     return proxy_detect_fn_streams({0: video}, {0: detector},
                                    _IdentityFrameOf(), max_out)
 
@@ -590,8 +589,7 @@ def evaluate_map_loop(video: SyntheticVideo, synced: Sequence[SyncedFrame],
                       ) -> float:
     """The seed's per-frame/per-class/per-detection Python-loop mAP —
     kept verbatim as the oracle for ``evaluate_map`` (tests assert
-    equality; ``benchmarks/nms_bench.py`` times the two against each
-    other)."""
+    equality)."""
     det_cache: Dict[int, Detections] = {}
     per_class_tp: Dict[int, List[float]] = {c: [] for c in
                                             range(video.N_CLASSES)}

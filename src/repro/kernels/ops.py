@@ -5,7 +5,7 @@ runs: compiled by Mosaic on a TPU backend, interpreted anywhere else
 (the kernel body runs as traced JAX ops, which checks results, not
 speed).  ``use_pallas=False`` falls back to
 the pure-jnp oracles in ref.py (the default inside model code, where XLA
-fusion already does well; benchmarks compare both paths).
+fusion already does well; tests compare both paths).
 
 NMS is the one exception to the "False means oracle" rule: the fused
 batched NMS has an XLA twin of the *same* tiled algorithm
@@ -13,8 +13,8 @@ batched NMS has an XLA twin of the *same* tiled algorithm
 Pallas runs interpreted, so ``batched_nms(use_pallas=False)`` routes
 there.  The slow oracles stay available as ``ref.nms_ref`` /
 ``ref.batched_nms_ref`` (tests assert bit-compatibility against them)
-and the seed's per-image serial path survives as ``nms_serial`` for
-benchmark baselines.
+and the seed's per-image serial path survives as ``nms_serial``
+(``tests/test_nms_fused.py`` checks the batched path against it).
 """
 from __future__ import annotations
 

@@ -1,6 +1,5 @@
 """Serving launcher: run the parallel-replica serving engine on any
-assigned architecture (smoke preset on CPU; the full configs are exercised
-via dryrun.py).
+assigned architecture (smoke preset on CPU).
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \\
       --n-replicas 4 --scheduler fcfs --requests 24

@@ -1,6 +1,4 @@
-"""Training launcher: real training steps on a reduced config (CPU), or
-mesh-sharded lowering for the full configs via --dry-run (see dryrun.py
-for the full sweep).
+"""Training launcher: real training steps on a reduced config (CPU).
 
   PYTHONPATH=src python -m repro.launch.train --arch minicpm-2b --steps 20
 """

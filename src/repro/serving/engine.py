@@ -382,7 +382,7 @@ class DetectionEngine:
       upgraded to motion-compensated prediction.
     * ``detect_fn`` swaps the mini-SSD for any ``(images, rids) ->
       (boxes, scores, classes, valid)`` callable (oracle detectors in
-      tests/benchmarks); ``service_time`` pins the virtual per-frame
+      tests); ``service_time`` pins the virtual per-frame
       service time so paced runs are deterministic.
     * Multi-camera (NVR): tag requests with ``stream_id`` and the SAME
       engine multiplexes every camera onto the shared replicas —

@@ -42,8 +42,8 @@ Fusion/donation rules
 The fused tick program traces the SAME jitted ``trk.step`` and
 ``trk.output`` the staged chain launches, so the op sequence is
 identical and the outputs are bit-identical (validated by
-``tests/test_pipeline.py`` / ``benchmarks/tick_bench.py``); only the
-launch count changes.  The incoming ``TrackerState`` is donated
+``tests/test_pipeline.py``); only the launch
+count changes.  The incoming ``TrackerState`` is donated
 (``donate_argnums=(0,)``): callers must thread the returned state and
 never reuse the argument.  On backends without donation support
 (XLA-CPU) the donation is a no-op — JAX keeps the input buffers valid
@@ -314,10 +314,10 @@ class TickPipeline:
     ``trk.step`` / ``trk.coast`` per tick, ``trk.output`` when the tick
     asks for it — through the ``tracking`` module attributes, exactly
     like the pre-refactor engines (the launch spies in
-    ``benchmarks/nvr_bench`` keep working).  ``fused=True`` runs the
-    one-jit donated-buffer program every tick, detections or not (an
-    all-invalid row is bit-identical to coasting), and returns the
-    tick's outputs for free.  ``launches`` counts tracker launches
+    ``tests/test_nvr_serving.py`` count them there).  ``fused=True``
+    runs the one-jit donated-buffer program every tick, detections or
+    not (an all-invalid row is bit-identical to coasting), and returns
+    the tick's outputs for free.  ``launches`` counts tracker launches
     either way — one per tick; ``resident`` is 1 when ``seed`` took the
     last segment's device table.
 

@@ -13,7 +13,7 @@ assertable."""
 import numpy as np
 import pytest
 
-from repro.core import proxy_detect_fn_streams
+from repro.core import evaluate_streams, proxy_detect_fn_streams
 from repro.core.executor import (DEVICE_PROFILES, MODEL_PROFILES,
                                  DetectorExecutor)
 from repro.core.scheduler import NoHealthyExecutorError, make_scheduler
@@ -360,10 +360,17 @@ def test_engine_track_mode_coasts_through_kill():
     sched = FaultSchedule.replica_kill(0.5, replica=1)
     eng, frames = nvr_engine(sched, n=16, track_and_interpolate=True)
     rep = eng.serve(frames)
+    assert sum(rep["retries"].values()) >= 1        # the kill was felt
     # tracker mode never leaves a gap: dropped arrivals are emitted with
     # coasted boxes, so per-stream coverage holds at 1.0 under the kill
     assert rep["coverage"] == 1.0
     assert all(v["coverage"] == 1.0 for v in rep["per_stream"].values())
+    # and quality holds within 20% of the fault-free serve
+    clean, _ = nvr_engine(None, n=16, track_and_interpolate=True)
+    _, _, videos, _ = make_nvr_streams(2, 16, rate=4.0)
+    q_clean = evaluate_streams(videos, clean.serve(frames)["streams"], 16)
+    q_kill = evaluate_streams(videos, rep["streams"], 16)
+    assert q_kill["map_mean"] >= 0.8 * q_clean["map_mean"]
 
 
 def test_engine_rejects_empty_pool():
@@ -398,14 +405,21 @@ def test_shard_events_require_rebalance():
 
 
 def test_sharded_no_fault_bit_identical():
+    """An empty schedule and an idle watchdog cost nothing: the
+    fault-free epoch loop serves the same rids on the same replicas at
+    the same clocks with or without them."""
+    def served(rep):
+        return ([(r.rid, r.replica, r.t_start, r.t_done)
+                 for r in rep["responses"]], rep["dropped"],
+                rep["migrations"], rep["per_replica"])
+
     eng0, frames = sharded_nvr()
     eng1, _ = sharded_nvr(faults=FaultSchedule())
-    r0, r1 = eng0.serve(frames), eng1.serve(frames)
+    eng2, _ = sharded_nvr(supervisor=Watchdog())
+    r0, r1, r2 = (e.serve(frames) for e in (eng0, eng1, eng2))
     assert set(r0) == set(r1)
-    assert [r.rid for r in r0["responses"]] == [r.rid
-                                                for r in r1["responses"]]
-    assert r0["dropped"] == r1["dropped"]
-    assert r0["migrations"] == r1["migrations"]
+    assert served(r0) == served(r1) == served(r2)
+    assert r2["faults"]["restarts"] == r2["faults"]["loans"] == []
 
 
 def test_shard_kill_recovers_within_epoch():

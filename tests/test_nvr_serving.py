@@ -157,11 +157,14 @@ def test_per_stream_accounting_sums_to_global():
     assert out["coverage"] == global_cov
 
 
-def test_eight_camera_tracked_run_full_coverage_one_launch_per_tick():
-    """The PR acceptance row: an 8-camera overloaded run under
-    track_and_interpolate completes with per-stream coverage 1.0,
-    exactly one tracker launch per tick, per-stream arrival order, and
-    a per-stream mAP win over the drop-frames baseline."""
+def test_eight_camera_tracked_run_full_coverage_one_launch_per_tick(
+        monkeypatch):
+    """An 8-camera overloaded run under track_and_interpolate completes
+    with per-stream coverage 1.0, exactly one tracker launch per tick
+    (counted at the ``trk.step``/``trk.coast`` call sites, not only by
+    the engine's own counter), per-stream arrival order, and a
+    per-stream mAP win over the drop-frames baseline."""
+    import repro.tracking as trk
     n_streams, n_frames = 8, 24
     frames, frame_of, videos, dets = make_streams(n_streams, n_frames,
                                                   rate=2.0)
@@ -172,11 +175,18 @@ def test_eight_camera_tracked_run_full_coverage_one_launch_per_tick():
         return eng.serve(frames)
 
     out_d = run(drop_when_busy=True)
+    calls = {"n": 0}
+    for name in ("step", "coast"):
+        def spy(*a, _launch=getattr(trk, name), **kw):
+            calls["n"] += 1
+            return _launch(*a, **kw)
+        monkeypatch.setattr(trk, name, spy)
     out_t = run(track_and_interpolate=True)
     assert out_t["coverage"] == 1.0
     assert out_t["n_streams"] == n_streams
     assert out_t["tracker_ticks"] == n_frames
     assert out_t["tracker_launches"] == n_frames    # one launch per tick
+    assert calls["n"] == n_frames
     for s in range(n_streams):
         v = out_t["per_stream"][s]
         assert v["coverage"] == 1.0

@@ -20,6 +20,7 @@ from repro.serving import (DetectionEngine, FaultSchedule, FrameRequest,
                            Request, ServingEngine,
                            ShardedDetectionEngine, Watchdog,
                            make_nvr_streams)
+from test_sharded_serving import assert_reports_identical
 
 
 def nvr(n_streams=4, n_frames=16, **kw):
@@ -61,6 +62,22 @@ def test_null_recorder_is_inert():
     assert rec.shard_view(3) is rec
     assert rec.to_json() == {"events": [], "series": []} or \
         rec.to_json() == {"events": [], "series": {}}
+
+
+@pytest.mark.parametrize("sharded", [False, True])
+def test_recorder_never_steers_the_report(sharded):
+    """Tracing observes the serve and never steers it: the default
+    engine, an explicit ``NullRecorder`` and a live ``TraceRecorder``
+    give the same report, bit for bit (the sharded case records
+    through per-shard views)."""
+    extra = (dict(n_shards=2, rebalance=True, epoch_s=2.0) if sharded
+             else {})
+    frames, kw = nvr(n_streams=4, n_frames=16, **extra)
+    cls = ShardedDetectionEngine if sharded else DetectionEngine
+    default = cls(**kw).serve(frames)
+    for rec in (NullRecorder(), TraceRecorder()):
+        assert_reports_identical(default,
+                                 cls(recorder=rec, **kw).serve(frames))
 
 
 # ============================================== latency histogram units

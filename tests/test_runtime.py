@@ -87,6 +87,22 @@ def test_chunked_ingest_matches_one_shot_sharded_faults(chunk):
     assert_reports_identical(base, out)
 
 
+@pytest.mark.parametrize("chunk", CHUNKS)
+def test_chunked_ingest_matches_one_shot_sharded_rebalance(chunk):
+    """Rebalancing epochs without faults: any chunking drains to the
+    batch report."""
+    frames, oracle = nvr_setup(n_streams=4, n_frames=12, rate=2.0)
+    kw = dict(detect_fn=oracle, n_shards=2, n_replicas=2,
+              service_time=0.3, track_and_interpolate=True,
+              rebalance=True, epoch_s=2.0)
+    base = ShardedDetectionEngine(**kw).serve(frames)
+    rt = ServingRuntime(ShardedDetectionEngine(**kw), streams=range(4))
+    feed_chunked(rt, frames, chunk)
+    out = rt.drain()
+    assert set(out) == set(base)
+    assert_reports_identical(base, out)
+
+
 @pytest.mark.parametrize("chunk", (1, 5))
 def test_chunked_ingest_matches_one_shot_sharded_static(chunk):
     frames, oracle = nvr_setup(n_streams=4, n_frames=8, rate=2.0)
@@ -463,6 +479,27 @@ def test_daemon_virtual_clock_matches_batch_and_audits():
     res = audit_recorder(rec)         # frame conservation et al.
     assert res.ok, res.violations[:3]
     assert bus.counts.get("detection", 0) > 0
+
+
+def test_daemon_drains_rebalancing_serve_through_the_bus():
+    """The daemon on the rebalancing sharded engine: every frame is
+    ingested and drained, the bus publishes every recorded event, and
+    the tapped trace is audit-clean."""
+    frames, oracle = nvr_setup(n_streams=4, n_frames=12, rate=2.0)
+    bus = EventBus()
+    rec = bus.recorder()
+    eng = ShardedDetectionEngine(
+        detect_fn=oracle, n_shards=2, n_replicas=2, service_time=0.3,
+        track_and_interpolate=True, rebalance=True, epoch_s=2.0,
+        recorder=rec)
+    daemon = ServingDaemon(ServingRuntime(eng, streams=range(4)),
+                           clock=VirtualClock(), chunk=4)
+    daemon.run(frames)
+    assert daemon.frames_ingested == len(frames)
+    assert daemon.runtime.frames_pending == 0
+    assert sum(bus.counts.values()) == len(rec.events)
+    res = audit_recorder(rec)
+    assert res.ok, res.violations[:3]
 
 
 def test_daemon_graceful_stop_drains_ingested_frames():

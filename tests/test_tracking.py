@@ -205,7 +205,7 @@ def test_interpolated_map_beats_stale_reuse():
     """The acceptance bar: on the synthetic benchmark video, filling
     dropped frames with tracker-coasted boxes beats the paper's
     stale-reuse fill at every tested executor count."""
-    for n in (1, 3):
+    for n in (1, 2, 3, 4):
         det = ParallelDetector("ETH-Sunnyday", "yolov3", ["ncs2"] * n)
         paced = simulate(FrameStream(det.video), det.scheduler)
         synced = SequenceSynchronizer().order(paced)
